@@ -13,7 +13,11 @@ import jax
 import numpy as np
 
 from repro.obs.metrics import percentiles
-from repro.obs.roofline import HBM_BW, ICI_BW, PEAK_FLOPS  # noqa: F401
+from repro.obs.roofline import PEAKS, V5E
+
+# the v5e entry of the keyed peak table, for the analytic v5e rows
+PEAK_FLOPS = PEAKS[V5E]["bf16_flops"]
+HBM_BW = PEAKS[V5E]["hbm_bw"]
 
 # Set by ``benchmarks.run --smoke``: CI-budget timing (fewer warmups/iters).
 SMOKE = False

@@ -65,6 +65,8 @@ def main(argv=None) -> None:
                          "export rides into the --json payload as "
                          "'obs_metrics'")
     args = ap.parse_args(argv)
+    from repro import compile_cache
+    compile_cache.enable()
 
     from benchmarks import common
     if args.smoke:

@@ -312,8 +312,9 @@ def _space_of(aval) -> str:
 
 
 def _is_ref(aval) -> bool:
-    return hasattr(aval, "memory_space") or type(aval).__name__ in (
-        "AbstractMemoryRef", "AbstractRef")
+    # plain arrays carry a memory_space too (the device space), so the
+    # aval type decides
+    return type(aval).__name__ in ("AbstractMemoryRef", "AbstractRef")
 
 
 def _dtype_name(aval) -> str:

@@ -65,7 +65,7 @@ def _trace_one(kernel_fn, plan: HaloPlan, num_filters: int, form: str,
                dtype, M: int):
     """jaxpr of one kernel call on ShapeDtypeStruct operands."""
     planes = jax.ShapeDtypeStruct(
-        (M, plan.rows.extent, plan.cols.extent), dtype)
+        (M, plan.rows.span, plan.cols.span), dtype)
     w = 2 * plan.rows.r + 1
     coeffs = _coeff_sds(num_filters, w, form, dtype)
     args = [planes, coeffs]

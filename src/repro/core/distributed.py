@@ -19,7 +19,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from repro.core.border_spec import quantize_constant
 from repro.core.borders import BorderSpec, gather_rows
@@ -125,7 +125,7 @@ def _filter2d_sharded_impl(frame: jax.Array, coeffs: jax.Array, mesh: Mesh,
         return y
 
     fn = shard_map(local, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_rep=False)
+                   check_vma=False)
     y = fn(x, coeffs, q_params) if rq is not None else fn(x, coeffs)
     return _un_nhwc(y, add_b, add_c)
 

@@ -48,7 +48,6 @@ from repro.core.requant import RequantSpec
 from repro.core.streaming import (_filter2d_streaming_impl,
                                   strip_height_for_vmem)
 from repro.kernels.filter2d import halo
-from repro.kernels.filter2d import kernel as K
 from repro.kernels.filter2d import ops
 from repro.obs import events as obs_events
 from repro.obs import metrics as obs_metrics
@@ -214,7 +213,7 @@ class CompiledFilter:
 
       1. a mesh was supplied            → ``'sharded'`` (halo-exchange);
       2. the whole plane fits the VMEM budget (pixel-cache regime —
-         ``stream_vmem_working_set`` of the frame-resident plan ≤
+         ``plan_vmem_working_set`` of the frame-resident plan ≤
          ``vmem_budget``)               → ``'pallas'`` (``regime='small'``);
       3. otherwise                      → ``'streaming'`` (row-buffer
          strip scan, strip height derived from the budget), falling back
@@ -255,26 +254,18 @@ class CompiledFilter:
         self._C = frame_shape[-1] if nd >= 3 else 1
         w, r = spec.window, spec.radius
         dt = jnp.dtype(spec.dtype)
-        db, acc_b, out_b = halo.datapath_byte_widths(dt, spec.requant)
+        acc_b = halo.datapath_byte_widths(dt, spec.requant)[1]
         same = spec.border.same_size
         Ho = self._H if same else max(self._H - 2 * r, 1)
         Wo = self._W if same else max(self._W - 2 * r, 1)
         # the pixel-cache (frame-resident) working set: the number 'auto'
         # compares against the budget — regime selection IS the paper's
         # small-frame vs row-buffer split, decided from static accounting.
-        # The output tile is lane-padded exactly as the small-regime plan
-        # lays it out, so this estimate equals plan_vmem_working_set of
-        # the plan 'small' would build (no under-budget mis-selection on
-        # narrow unaligned frames). A 1-strip plan never double-banks the
-        # halo scratch (nothing to prefetch), but a bank grid (N > 1)
-        # still double-banks the output tile for the async store.
-        wo_pad = Wo + (-Wo) % halo.LANE
-        self.resident_vmem_bytes = K.stream_vmem_working_set(
-            Ho, wo_pad, w, db, separable=spec.separable,
-            num_filters=spec.num_filters, acc_dtype_bytes=acc_b,
-            out_dtype_bytes=out_b,
-            out_banks=2 if (self.overlap and spec.num_filters > 1) else 1)
-
+        # It is plan_vmem_working_set of the very plan 'small' would build
+        # (buffers and the body's widened window, live products and
+        # accumulator), so 'auto' never picks a plane the kernel's VMEM
+        # cannot hold.
+        self.resident_vmem_bytes = self._resident_vmem_bytes()
         requested = execution
         if execution == "auto":
             if mesh is not None:
@@ -339,7 +330,7 @@ class CompiledFilter:
                     self._H, self._W, w, dtype=dt,
                     vmem_budget=self.vmem_budget,
                     num_filters=spec.num_filters, separable=spec.separable,
-                    requant=spec.requant, same_size=same,
+                    requant=spec.requant, border=spec.border,
                     strip_h=strip_h, tile_w=tile_w, overlap=self.overlap)
             elif self.regime == "small":
                 strip_h = Ho if strip_h is None else strip_h
@@ -415,9 +406,9 @@ class CompiledFilter:
                 has_mesh=self.mesh is not None))
         eb = ob = None
         if self.execution == "pallas" and self.plan is not None:
-            eb, ob = K.plan_banks(self.plan,
-                                  num_filters=self.spec.num_filters,
-                                  overlap=self.overlap)
+            eb, ob = halo.plan_banks(self.plan,
+                                     num_filters=self.spec.num_filters,
+                                     overlap=self.overlap)
         ws = self.vmem_working_set()
         bpp = self.hbm_bytes_per_pixel()
         obs_events.emit(obs_events.CompileEvent(
@@ -430,6 +421,19 @@ class CompiledFilter:
             hbm_bytes_per_pixel=None if bpp is None else float(bpp),
             wall_ms=wall_s * 1e3))
         obs_metrics.REGISTRY.counter("pipeline.compiles").inc()
+
+    def _resident_vmem_bytes(self) -> int:
+        """plan_vmem_working_set of the frame-resident ('small') plan."""
+        spec, w = self.spec, self.spec.window
+        S, Tw, _, _ = ops.resolve_strip_tile(self._H, self._W, w,
+                                             spec.border, "small", 0, 0)
+        plan = halo.make_plan(
+            self._H, self._W, w, spec.border, S, Tw, dtype=spec.dtype,
+            requant=(spec.requant.gain_free()
+                     if spec.requant is not None else None))
+        return halo.plan_vmem_working_set(
+            plan, num_filters=spec.num_filters, separable=spec.separable,
+            overlap=self.overlap)
 
     def _streaming_strip(self, dtype_bytes: int) -> int:
         """Largest divisor of H within the budget-derived strip height
@@ -659,7 +663,7 @@ class CompiledFilter:
         both scratch banks counted when the double-buffered path runs."""
         if self.plan is None:
             return None
-        return K.plan_vmem_working_set(
+        return halo.plan_vmem_working_set(
             self.plan, num_filters=self.spec.num_filters,
             separable=self.spec.separable,
             overlap=self.overlap if self.execution == "pallas" else False)
@@ -675,8 +679,8 @@ class CompiledFilter:
         the double-buffering degree; ``(None, None)`` off the Pallas path."""
         if self.execution != "pallas" or self.plan is None:
             return None, None
-        return K.plan_banks(self.plan, num_filters=self.spec.num_filters,
-                            overlap=self.overlap)
+        return halo.plan_banks(self.plan, num_filters=self.spec.num_filters,
+                               overlap=self.overlap)
 
     def verify(self, grid_orders=None):
         """Run the static kernel verifier over this compiled pipeline.
@@ -699,8 +703,10 @@ class CompiledFilter:
         ``vmem_working_set()`` / ``hbm_bytes_per_pixel()`` /
         ``halo.read_amplification`` — restated, not re-derived (pinned to
         exact agreement in ``tests/test_obs.py``), plus the two-ceiling
-        roofline prediction from :mod:`repro.obs.roofline`. ``as_dict=True``
-        returns the machine-readable twin the bench harness consumes.
+        roofline prediction from :mod:`repro.obs.roofline` for the device
+        this process runs on (none for a device without published peaks).
+        ``as_dict=True`` returns the machine-readable twin the bench
+        harness consumes.
         ``verify=True`` runs :meth:`verify` first (if not already cached)
         so the report carries the static checker's verdict.
         """
@@ -713,7 +719,9 @@ class CompiledFilter:
         macs = macs_per_pixel(spec.window, form=spec.form,
                               separable=spec.separable)
         flops = 2.0 * macs * spec.num_filters
-        roof = obs_roofline.predicted_pixel_rate(flops, bpp)
+        roof = obs_roofline.predicted_pixel_rate(
+            flops, bpp, jax.devices()[0].device_kind,
+            integer=is_fixed_point(jnp.dtype(spec.dtype)))
         d = {
             "spec": {
                 "window": spec.window, "form": spec.form,
@@ -805,11 +813,16 @@ class CompiledFilter:
                 f"(read {h['read_bytes_per_pixel']:.3f} + write "
                 f"{h['write_bytes_per_pixel']:.3f}), read amplification "
                 f"{h['read_amplification']:.4f}x")
-        lines.append(
-            f"  roofline  {r['predicted_pixels_per_s']:.3e} px/s "
-            f"({r['bound']}-bound; {r['flops_per_pixel']:.0f} flop/px, "
-            + (f"{r['bytes_per_pixel']:.3f} B/px)" if r["bytes_per_pixel"]
-               is not None else "bytes unknown)"))
+        if r["predicted_pixels_per_s"] is None:
+            lines.append(f"  roofline  {r['why']}")
+        else:
+            lines.append(
+                f"  roofline  {r['predicted_pixels_per_s']:.3e} px/s on "
+                f"{r['device_kind']} ({r['bound']}-bound; "
+                f"{r['flops_per_pixel']:.0f} flop/px, "
+                + (f"{r['bytes_per_pixel']:.3f} B/px)"
+                   if r["bytes_per_pixel"] is not None
+                   else "bytes unknown)"))
         vr = d.get("verify")
         if vr is not None:
             if vr["error"] is not None:

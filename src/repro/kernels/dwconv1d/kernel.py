@@ -16,7 +16,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-from repro.kernels._compat import CompilerParams
 
 
 def _dwconv1d_kernel(x_ref, w_ref, b_ref, o_ref, carry_ref, *, k: int,
@@ -60,7 +59,7 @@ def dwconv1d(x: jax.Array, w: jax.Array, b: jax.Array, *, chunk: int = 512,
         out_specs=pl.BlockSpec((1, chunk, C), lambda b_, j: (b_, j, 0)),
         scratch_shapes=[pltpu.VMEM((k - 1, C), x.dtype)],
         interpret=interpret,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         name="dwconv1d_stream",
     )(x, w, b)

@@ -44,7 +44,7 @@ class KernelContract:
     invars in positional order (inputs, then outputs, then scratch — the
     order Pallas binds them). ``axes`` names the grid dims in grid order.
     ``ext_banks``/``out_banks`` are the bank counts the kernel allocates
-    (:func:`~repro.kernels.filter2d.kernel.plan_banks`); ``serial_ref``
+    (:func:`~repro.kernels.filter2d.halo.plan_banks`); ``serial_ref``
     marks the contract of the one-bank reference path whose fill schedule
     defines correct scratch contents for the banked kernel.
     """

@@ -12,11 +12,12 @@ mirroring the paper's):
               grid is (planes, column tiles, row strips, filters) and
               streams row strips sequentially within each lane-aligned
               column tile. Each strip step DMAs its S+2r input rows (the
-              paper's w−1 row buffer, plus the strip body) straight from
+              paper's w−1 row buffer, plus the strip body; rounded out to
+              whole (8, 128) tiles, see ``halo``) straight from
               the **un-tiled frame in HBM** into the VMEM scratch — there
               is no pre-tiled, halo-duplicated HBM layout anywhere. The
               per-step VMEM working set is bounded by strip_h × tile_w
-              (see :func:`stream_vmem_working_set`), independent of frame
+              (see ``halo.stream_vmem_working_set``), independent of frame
               height AND width — arbitrary-width (8K) frames stream under
               a fixed strip budget.
 
@@ -62,10 +63,10 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.filter2d import apply_requant, is_fixed_point
-from repro.kernels._compat import CompilerParams
 from repro.kernels.filter2d import halo
 from repro.kernels.filter2d.contract import KernelContract
-from repro.kernels.filter2d.halo import HaloPlan
+from repro.kernels.filter2d.halo import (HaloPlan, plan_banks,
+                                         plan_vmem_working_set)
 
 LANE = halo.LANE  # TPU lane width: last-dim alignment target
 
@@ -92,13 +93,15 @@ def out_dtype(plan: HaloPlan, storage_dtype):
     return acc_dtype(storage_dtype)
 
 
-def _reduce_taps(ext, coeffs, Ho: int, Wo: int, w: int, form: str):
-    """w² shifted-product reduction in the requested layout. ext: [Ho+2r, *]."""
+def _reduce_taps(ext, coeffs, Ho: int, Wo: int, w: int, form: str,
+                 r0: int = 0, c0: int = 0):
+    """w² shifted-product reduction in the requested layout. ext holds the
+    halo window with the first tap at (r0, c0)."""
     prods = []
     acc = None
     for i in range(w):
         for j in range(w):
-            plane = ext[i:i + Ho, j:j + Wo] * coeffs[i, j]
+            plane = ext[r0 + i:r0 + i + Ho, c0 + j:c0 + j + Wo] * coeffs[i, j]
             if form == "transposed":     # MAC chain, running accumulator
                 acc = plane if acc is None else acc + plane
             else:
@@ -132,15 +135,18 @@ def _reduce_taps(ext, coeffs, Ho: int, Wo: int, w: int, form: str):
     raise ValueError(form)
 
 
-def _reduce_separable(ext, u, v, Ho: int, Wo: int, w: int):
+def _reduce_separable(ext, u, v, Ho: int, Wo: int, w: int,
+                      r0: int = 0, c0: int = 0):
     """Fused separable reduction: w-tap column pass then w-tap row pass.
 
-    ext: [Ho+2r, Wo+2r(+pad)]; u/v: [w] row/column factors. 2w MACs/pixel
-    (the column pass runs on Ho+2r rows, amortised over the strip).
+    ext: the halo window with the first tap at (r0, c0); u/v: [w] row/
+    column factors. 2w MACs/pixel (the column pass runs on Ho+2r rows,
+    amortised over the strip).
     """
+    ext = ext[r0:r0 + Ho + w - 1]
     h = None
     for j in range(w):                   # column (horizontal) pass
-        t = ext[:, j:j + Wo] * v[j]
+        t = ext[:, c0 + j:c0 + j + Wo] * v[j]
         h = t if h is None else h + t
     y = None
     for i in range(w):                   # row (vertical) pass
@@ -155,23 +161,6 @@ def _reduce_separable(ext, u, v, Ho: int, Wo: int, w: int):
 
 
 GRID_ORDERS = ("filters_innermost", "strips_innermost")
-
-
-def plan_banks(plan: HaloPlan, num_filters: int = 1,
-               overlap: bool = True) -> tuple:
-    """(ext_banks, out_banks) the kernel allocates for this plan.
-
-    The input scratch is double-banked only when there is a next strip to
-    prefetch (``rows.n > 1``); the output buffer only when there is a
-    later step to pre-wait behind (more than one (strip, filter) step per
-    tile). Single-strip single-filter plans collapse both to 1 bank — the
-    serial working set — so the pixel-cache regime pays nothing for the
-    overlap machinery it cannot use."""
-    if not overlap:
-        return 1, 1
-    ext_banks = 2 if plan.rows.n > 1 else 1
-    out_banks = 2 if plan.rows.n * num_filters > 1 else 1
-    return ext_banks, out_banks
 
 
 def kernel_contract(plan: HaloPlan, num_filters: int = 1,
@@ -220,7 +209,7 @@ def _halo_kernel(x_ref, c_ref, *rest, plan: HaloPlan, form: str, w: int,
 
     x_ref is the whole un-tiled [M, H, W] plane stack in ANY/HBM space —
     the kernel's own DMA is the only reader, so the stream is read-once
-    from HBM (plus the 2r strip overlap). The scratch persists across the
+    from HBM (plus the aligned strip overlap). The scratch persists across the
     filter steps whenever filters are the innermost grid dim: the
     coefficient-file read-once property. With ``grid_order=
     'strips_innermost'`` every step is a fresh strip, so the fill is
@@ -302,12 +291,17 @@ def _halo_kernel(x_ref, c_ref, *rest, plan: HaloPlan, form: str, w: int,
     # fixed-point: the scratch holds the narrow storage dtype (the DMA'd
     # bytes stay 1-2 per pixel); the widening to the int32 accumulator
     # happens here, on the register-level read feeding the MAC.
+    # The aligned window is loaded whole and the over-fetch is shifted
+    # away by the tap offsets (r0, c0).
     adt = jnp.int32 if plan.requant is not None else o_ref.dtype
-    ext = ext_bank[...].astype(adt)
+    ext = ext_bank[pl.ds(0, plan.rows.window),
+                   pl.ds(0, plan.cols.window)].astype(adt)
+    r0, c0 = plan.rows.shift, plan.cols.shift
     if form == "separable":
-        y = _reduce_separable(ext, c_ref[0, 0], c_ref[0, 1], S, Tw, w)
+        y = _reduce_separable(ext, c_ref[0, 0], c_ref[0, 1], S, Tw, w,
+                              r0, c0)
     else:
-        y = _reduce_taps(ext, c_ref[0], S, Tw, w, form)
+        y = _reduce_taps(ext, c_ref[0], S, Tw, w, form, r0, c0)
     if plan.requant is not None:
         # the fused epilogue: word growth managed inside the datapath, so
         # the store (and the HBM write behind it) is storage-width again
@@ -378,14 +372,20 @@ def filter2d_halo(planes: jax.Array, coeffs: jax.Array, plan: HaloPlan, *,
     output banks (each store is issued async and waited two steps later),
     per-bank DMA semaphores. ``overlap=False`` is the serial reference:
     one bank, start+wait fill, BlockSpec store — bit-identical output.
-    VMEM per step: banks × [(S+2r)×(Tw+2r lane-padded) scratch + S×Tw
-    output block] + the coefficient file (see
-    :func:`plan_vmem_working_set`) — still the row-buffer bound,
-    independent of both frame height and width.
+    planes must already be padded to the plan's tile-aligned span
+    (``rows.span × cols.span``). VMEM per step: the banked scratch and
+    output tiles, the coefficient file and what the body materialises
+    (see :func:`plan_vmem_working_set`) — still the row-buffer bound,
+    independent of both frame height and width; the compiler's VMEM
+    limit is sized from it (:func:`vmem_limit_bytes`).
     """
     if grid_order not in GRID_ORDERS:
         raise ValueError(f"unknown grid_order {grid_order!r}; choose from "
                          f"{GRID_ORDERS}")
+    span = (plan.rows.span, plan.cols.span)
+    if tuple(planes.shape[1:]) != span:
+        raise ValueError(f"planes {tuple(planes.shape[1:])} are not the "
+                         f"plan's tile-aligned span {span}; pad them")
     w = coeffs.shape[-1]
     M = planes.shape[0]
     N = coeffs.shape[0]
@@ -402,7 +402,7 @@ def filter2d_halo(planes: jax.Array, coeffs: jax.Array, plan: HaloPlan, *,
         o_map = lambda m, jj, f, ii: (m, f, ii, jj)   # noqa: E731
         grid = (M, n_j, N, n_i)
     in_specs = [
-        pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY),
+        pl.BlockSpec(memory_space=pl.ANY),
         pl.BlockSpec(c_block, c_map),
     ]
     operands = [planes, coeffs]
@@ -417,14 +417,14 @@ def filter2d_halo(planes: jax.Array, coeffs: jax.Array, plan: HaloPlan, *,
         if q_params is None:
             q_params = jnp.asarray(plan.requant.params(N), jnp.int32)
         operands.append(q_params)
-        in_specs.append(pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.SMEM))
+        in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
         name += f"_requant_{plan.requant.rounding}"
     odt = out_dtype(plan, planes.dtype)
     ext_banks, out_banks = plan_banks(plan, N, overlap)
     if overlap:
         # the output is ANY-space: the kernel owns the stores (manual
         # async copies from the obuf banks), not a BlockSpec
-        out_spec = pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY)
+        out_spec = pl.BlockSpec(memory_space=pl.ANY)
         scratch = [pltpu.VMEM((ext_banks, plan.eh, plan.ew), planes.dtype),
                    pltpu.VMEM((out_banks, S, Tw), odt),
                    pltpu.SemaphoreType.DMA((ext_banks,)),
@@ -445,75 +445,24 @@ def filter2d_halo(planes: jax.Array, coeffs: jax.Array, plan: HaloPlan, *,
         out_specs=out_spec,
         scratch_shapes=scratch,
         interpret=interpret,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary",
-                                 "arbitrary")),
+                                 "arbitrary"),
+            vmem_limit_bytes=vmem_limit_bytes(plan_vmem_working_set(
+                plan, num_filters=N, separable=form == "separable",
+                overlap=overlap))),
         name=name,
     )(*operands)
 
 
-def plan_vmem_working_set(plan: HaloPlan, *, num_filters: int = 1,
-                          separable: bool = False,
-                          overlap: bool = True) -> int:
-    """VMEM bytes per grid step straight from a *built* plan.
-
-    The plan-exact twin of :func:`stream_vmem_working_set`: the scratch is
-    the plan's own ``eh × ew`` (lane padding and halo margins included) at
-    storage width, the output tile ``strip × tile`` at the plan's write
-    width, and the coefficient file at the accumulator width — each buffer
-    multiplied by the bank count :func:`plan_banks` says the kernel
-    actually allocates (2 each in the overlapped steady state, collapsing
-    to 1 where the plan has nothing to prefetch or pre-wait). This is what
-    the ``CompiledFilter`` front door reports (and what its
-    ``execution='auto'`` selection audits against the ``vmem_budget``
-    knob) — one number per compiled pipeline, no re-derivation."""
-    w = 2 * plan.rows.r + 1
-    ext_banks, out_banks = plan_banks(plan, num_filters, overlap)
-    scratch = ext_banks * plan.eh * plan.ew * plan.dtype_bytes
-    out_tile = (out_banks * plan.rows.block * plan.cols.block
-                * plan.out_dtype_bytes)
-    coeff = num_filters * (2 * w if separable else w * w) * plan.acc_bytes
-    return scratch + out_tile + coeff
+# The compiler's own default scoped VMEM limit is 16 MiB. The limit each
+# kernel asks for is its planned working set with 2x headroom for what
+# Mosaic adds (its internal scratch, relayout copies), never below that
+# default and well inside a v5e core's VMEM.
+VMEM_LIMIT_FLOOR = 16 * 2 ** 20
+VMEM_LIMIT_CAP = 100 * 2 ** 20
 
 
-def stream_vmem_working_set(strip_h: int, tile_w: int, w: int,
-                            dtype_bytes: int = 4, *,
-                            separable: bool = False,
-                            num_filters: int = 1,
-                            acc_dtype_bytes: int = None,
-                            out_dtype_bytes: int = None,
-                            ext_banks: int = 1,
-                            out_banks: int = 1) -> int:
-    """Bytes resident in VMEM per stream grid step (the row-buffer bound).
-
-    ``ext_banks`` × the halo-extended scratch + ``out_banks`` × the output
-    tile + the coefficient file. A function of (strip_h, tile_w, w, banks)
-    ONLY — never of the frame dimensions; this is the invariant the 2D
-    tiling exists to provide. The in-kernel halo engine keeps the scratch
-    single-purpose (strip buffer AND line buffer in one block, DMA'd from
-    HBM directly — no second input tile); the double-buffered kernel banks
-    that scratch and the output tile ×2 (pass the counts
-    :func:`plan_banks` computes) to overlap the next strip's DMA and the
-    previous tile's store with the reduction.
-
-    Dtype-aware in both directions: ``dtype_bytes`` is the *storage* width
-    (the scratch the DMA fills), ``acc_dtype_bytes`` the accumulator width
-    (defaults to the storage width — pass 4 for the fixed-point
-    int8/int16-in datapath, where the scratch shrinks 4×/2× but the
-    coefficient file stays wide), and ``out_dtype_bytes`` the width of the
-    output tile (defaults to the accumulator width; pass the storage width
-    when the plan carries the requantising epilogue — the output tile then
-    shrinks 4× along with the write-side HBM traffic, freeing VMEM for
-    deeper strips).
-    """
-    if acc_dtype_bytes is None:
-        acc_dtype_bytes = dtype_bytes
-    if out_dtype_bytes is None:
-        out_dtype_bytes = acc_dtype_bytes
-    r = (w - 1) // 2
-    ew = tile_w + 2 * r
-    ew += (-ew) % LANE                   # lane padding, as the plan lays out
-    ext_scratch = ext_banks * (strip_h + 2 * r) * ew * dtype_bytes
-    out_tile = out_banks * strip_h * tile_w * out_dtype_bytes
-    coeff = num_filters * (2 * w if separable else w * w) * acc_dtype_bytes
-    return ext_scratch + out_tile + coeff
+def vmem_limit_bytes(working_set: int) -> int:
+    """The ``vmem_limit_bytes`` the kernel compiles under."""
+    return int(min(max(2 * working_set, VMEM_LIMIT_FLOOR), VMEM_LIMIT_CAP))

@@ -21,9 +21,9 @@ DMA from the un-tiled frame plus an in-VMEM index mux. The old row-extended,
 halo-duplicated HBM staging layout (one extra full-frame HBM pass ahead of
 the kernel) is gone: the kernel's input operand IS the raw frame, read once.
 
-On non-TPU backends kernels run in ``interpret=True`` mode (bit-accurate
-Python execution of the kernel body) — the TPU lowering is exercised by the
-dry-run path.
+Without an ``interpret`` argument, kernels run compiled on a TPU backend
+and in ``interpret=True`` mode (bit-accurate Python execution of the
+kernel body) elsewhere; ``CompiledFilter.interpret`` reports which.
 """
 from __future__ import annotations
 
@@ -85,8 +85,9 @@ def resolve_strip_tile(H: int, W: int, w: int, border: BorderSpec,
     """Clamp caller strip/tile knobs into plan geometry: ``(S, Tw, Ho, Wo)``.
 
     ``small`` is the pixel-cache regime (one strip × one lane-padded tile =
-    the whole plane resident); ``stream`` clamps strips so multi-strip
-    plans keep ``S >= 2r`` (only the first/last strips ever touch a frame
+    the whole plane resident); ``stream`` aligns strips to whole sublane
+    tiles of at least 2r rows (``halo.align_strip``: every strip origin
+    stays tile-aligned, and only the first/last strips ever touch a frame
     edge) and lane-aligns column tiles. Shared by the kernel wrapper and
     the ``CompiledFilter`` planner so the accounting plan the pipeline
     reports is byte-identical to the plan the kernel runs."""
@@ -98,7 +99,7 @@ def resolve_strip_tile(H: int, W: int, w: int, border: BorderSpec,
     if regime == "small":
         S, Tw = Ho, Wo + ((-Wo) % LANE)
     elif regime == "stream":
-        S = max(min(strip_h, Ho), min(2 * r, Ho), 1)
+        S = halo.align_strip(max(int(strip_h), 1), Ho, r)
         Tw = min(tile_w + ((-tile_w) % LANE), Wo + ((-Wo) % LANE))
     else:
         raise ValueError(regime)
@@ -137,6 +138,12 @@ def _filter2d_pallas_planes(planes: jax.Array, coeffs: jax.Array,
     # and the requant spec (when set) makes the write side narrow too.
     plan = halo.make_plan(H, W, w, border, S, Tw, dtype=planes.dtype,
                           requant=requant)
+    # the kernel's DMAs move whole (8, 128) tiles: a frame that is not a
+    # whole number of tiles is zero-padded up to the plan's span (one
+    # extra HBM pass; the halo mux never reads the pad as frame data)
+    pad_h, pad_w = plan.rows.span - H, plan.cols.span - W
+    if pad_h or pad_w:
+        planes = jnp.pad(planes, ((0, 0), (0, pad_h), (0, pad_w)))
     # trace-time op-name prefix only (profiler/HLO readability):
     # named_scope costs nothing at runtime and survives jax.export
     with jax.named_scope(f"repro.filter2d.pallas.{regime}"):

@@ -22,7 +22,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-from repro.kernels._compat import CompilerParams
 
 NEG_INF = -1e30
 
@@ -116,7 +115,7 @@ def swattn(q: jax.Array, k: jax.Array, v: jax.Array, *, window: int,
             pltpu.VMEM((blk, hd), jnp.float32),
         ],
         interpret=interpret,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         name=f"swattn_w{window}",
     )(q, k, v)

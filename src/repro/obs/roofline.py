@@ -1,49 +1,75 @@
-"""Analytic roofline arithmetic + the peak constants it is stated in.
+"""Analytic roofline arithmetic + the published peaks it is stated in.
 
-One source of truth for the TPU v5e peak numbers the whole repo quotes:
-``benchmarks/common.py`` re-exports these (it historically owned them),
-``CompiledFilter.explain()`` derives its predicted pixel rate from them,
-and the ROADMAP's measured-autotune item will calibrate against them.
+One source of truth for the device peaks the whole repo quotes, keyed by
+``device_kind`` as JAX reports it (``jax.devices()[0].device_kind``):
+``CompiledFilter.explain()`` derives its predicted pixel rate from the
+entry of the device it runs on, and ``benchmarks/common.py`` re-exports
+the v5e entry for its analytic rows. A device that is not in the table
+gets no prediction — never another device's numbers.
+
 The model is the classic two-ceiling roofline (the dace ``RooflineModel``
-pattern): a kernel that issues ``f`` flops and moves ``b`` HBM bytes per
-output pixel sustains at most ``min(PEAK_FLOPS / f, HBM_BW / b)``
-pixels/s — the filter datapaths here are firmly memory-bound, which is
-why every tentpole so far attacked bytes/pixel rather than MACs.
+pattern): a kernel that issues ``f`` operations and moves ``b`` HBM bytes
+per output pixel sustains at most ``min(peak_ops / f, hbm_bw / b)``
+pixels/s. The op peaks are the MXU's; the filter's MAC loop runs on the
+VPU, so the compute ceiling is an upper bound it does not reach.
 """
 from __future__ import annotations
 
 from typing import Dict, Optional
 
-__all__ = ["PEAK_FLOPS", "HBM_BW", "ICI_BW", "predicted_pixel_rate"]
+__all__ = ["PEAKS", "V5E", "peaks", "predicted_pixel_rate"]
 
-# TPU v5e targets (per brief) — used for analytic pixel-rate derivations
-PEAK_FLOPS = 197e12
-HBM_BW = 819e9
-ICI_BW = 50e9
+V5E = "TPU v5 lite"
+
+# Published per-chip peaks. TPU v5e: Google Cloud documentation, "TPU
+# v5e" (cloud.google.com/tpu/docs/v5e, system architecture table): 197
+# TFLOP/s bf16, 393 TOP/s int8, 16 GB of HBM at 819 GB/s.
+PEAKS: Dict[str, Dict[str, float]] = {
+    V5E: {"bf16_flops": 197e12, "int8_ops": 393e12, "hbm_bw": 819e9},
+}
+
+
+def peaks(device_kind: str) -> Optional[Dict[str, float]]:
+    """The published peaks of ``device_kind``, or None when unknown."""
+    return PEAKS.get(device_kind)
 
 
 def predicted_pixel_rate(flops_per_pixel: float,
                          bytes_per_pixel: Optional[float],
-                         peak_flops: float = PEAK_FLOPS,
-                         hbm_bw: float = HBM_BW) -> Dict[str, float]:
-    """Both roofline ceilings and the binding one, per output pixel.
+                         device_kind: str,
+                         integer: bool = False) -> Dict[str, object]:
+    """Both roofline ceilings and the binding one, per output pixel, on
+    ``device_kind`` (the int8 op peak for ``integer`` datapaths, else
+    bf16).
 
     Returns ``compute_bound_pixels_per_s``, ``memory_bound_pixels_per_s``
     (``inf`` when the respective cost is zero/unknown), the ``min`` of the
     two as ``predicted_pixels_per_s``, and ``bound`` naming the ceiling.
+    For a device with no published peaks the rates are None and ``why``
+    says so.
     """
-    compute = (peak_flops / flops_per_pixel if flops_per_pixel
-               else float("inf"))
-    memory = (hbm_bw / bytes_per_pixel if bytes_per_pixel
-              else float("inf"))
-    return {
+    out: Dict[str, object] = {
+        "device_kind": device_kind,
         "flops_per_pixel": float(flops_per_pixel),
         "bytes_per_pixel": (float(bytes_per_pixel)
                             if bytes_per_pixel else None),
-        "compute_bound_pixels_per_s": compute,
-        "memory_bound_pixels_per_s": memory,
-        "predicted_pixels_per_s": min(compute, memory),
-        "bound": "compute" if compute < memory else "memory",
-        "peak_flops": float(peak_flops),
-        "hbm_bw": float(hbm_bw),
     }
+    p = peaks(device_kind)
+    if p is None:
+        out.update(predicted_pixels_per_s=None, bound=None,
+                   why=f"no published peaks for device kind "
+                       f"{device_kind!r}: no roofline prediction")
+        return out
+    peak_ops = p["int8_ops"] if integer else p["bf16_flops"]
+    compute = (peak_ops / flops_per_pixel if flops_per_pixel
+               else float("inf"))
+    memory = (p["hbm_bw"] / bytes_per_pixel if bytes_per_pixel
+              else float("inf"))
+    out.update(
+        compute_bound_pixels_per_s=compute,
+        memory_bound_pixels_per_s=memory,
+        predicted_pixels_per_s=min(compute, memory),
+        bound="compute" if compute < memory else "memory",
+        peak_flops=float(peak_ops),
+        hbm_bw=float(p["hbm_bw"]))
+    return out

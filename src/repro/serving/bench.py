@@ -43,7 +43,7 @@ from typing import List, Optional
 
 import numpy as np
 
-from repro import obs
+from repro import compile_cache, obs
 from repro.core import filters
 from repro.core.border_spec import BorderSpec
 from repro.core.pipeline import Filter2D, batched_shape
@@ -259,6 +259,7 @@ def main(argv=None) -> int:
                     help="stream obs events (incl. serve_wave) to this "
                          "JSONL file")
     args = ap.parse_args(argv)
+    compile_cache.enable()
 
     obs.enable(jsonl=args.obs_jsonl)
     try:

@@ -22,7 +22,7 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from repro.configs.base import RunConfig
 from repro.optim import adamw_update, clip_by_global_norm, cosine_warmup
@@ -87,7 +87,7 @@ def make_compressed_dp_step(bundle, rc: RunConfig, mesh: Mesh) -> Callable:
         local_step, mesh=mesh,
         in_specs=(rep, rep, err_spec, batch_spec),
         out_specs=(rep, rep, err_spec, rep),
-        check_rep=False)
+        check_vma=False)
     return jax.jit(fn)
 
 

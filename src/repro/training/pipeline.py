@@ -21,7 +21,7 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 
 def pipeline_apply(layer_fn: Callable, params_stacked, x_mb: jax.Array,
@@ -74,7 +74,7 @@ def pipeline_apply(layer_fn: Callable, params_stacked, x_mb: jax.Array,
     pspec = jax.tree.map(lambda _: P(axis), params_stacked)
     fn = shard_map(local, mesh=mesh,
                    in_specs=(pspec, P()), out_specs=P(),
-                   check_rep=False)
+                   check_vma=False)
     return fn(params_stacked, x_mb)
 
 

@@ -17,7 +17,6 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core.border_spec import BorderSpec
 from repro.core.filter2d import apply_requant
 from repro.core.requant import RequantSpec
-from repro.kernels._compat import CompilerParams
 from repro.kernels.filter2d import halo
 from repro.kernels.filter2d import kernel as K
 
@@ -63,15 +62,18 @@ def _bugged_kernel(x_ref, c_ref, *rest, plan, w, n_filters, grid_order,
                                fill_sem.at[bank], i, j, plan))
 
     adt = jnp.int32 if plan.requant is not None else o_ref.dtype
+    win = ext_ref.at[bank][pl.ds(0, plan.rows.window),
+                           pl.ds(0, plan.cols.window)]
+    r0, c0 = plan.rows.shift, plan.cols.shift
     if bug == "widen_mac":
         # BUG: the narrow stream widens to FLOAT at the MAC input — the
         # fixed-point datapath allows the int32 accumulator only
-        ext = ext_ref.at[bank][...].astype(jnp.float32)
+        ext = win.astype(jnp.float32)
         y = K._reduce_taps(ext, c_ref[0].astype(jnp.float32), S, Tw, w,
-                           "direct").astype(jnp.int32)
+                           "direct", r0, c0).astype(jnp.int32)
     else:
-        ext = ext_ref.at[bank][...].astype(adt)
-        y = K._reduce_taps(ext, c_ref[0], S, Tw, w, "direct")
+        ext = win.astype(adt)
+        y = K._reduce_taps(ext, c_ref[0], S, Tw, w, "direct", r0, c0)
     if plan.requant is not None:
         y = apply_requant(y, q_ref[f, 0], q_ref[f, 1],
                           rounding=plan.requant.rounding,
@@ -118,7 +120,7 @@ def _build_call(plan, bug, num_filters, grid_order, dtype):
     S, Tw = plan.rows.block, plan.cols.block
     n_i, n_j = plan.rows.n, plan.cols.n
     N = num_filters
-    ext_banks, out_banks = K.plan_banks(plan, N, True)
+    ext_banks, out_banks = halo.plan_banks(plan, N, True)
     odt = K.out_dtype(plan, jnp.dtype(dtype))
 
     def kernel_fn(planes, coeffs, q=None):
@@ -129,13 +131,13 @@ def _build_call(plan, bug, num_filters, grid_order, dtype):
         else:
             c_map = lambda m, jj, f, ii: (f, 0, 0)        # noqa: E731
             grid = (M, n_j, N, n_i)
-        in_specs = [pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY),
+        in_specs = [pl.BlockSpec(memory_space=pl.ANY),
                     pl.BlockSpec((1, w, w), c_map)]
         operands = [planes, coeffs]
         if plan.requant is not None:
             operands.append(q)
             in_specs.append(
-                pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.SMEM))
+                pl.BlockSpec(memory_space=pltpu.SMEM))
         return pl.pallas_call(
             functools.partial(_bugged_kernel, plan=plan, w=w, n_filters=N,
                               grid_order=grid_order, ext_banks=ext_banks,
@@ -143,14 +145,14 @@ def _build_call(plan, bug, num_filters, grid_order, dtype):
             out_shape=jax.ShapeDtypeStruct((M, N, n_i * S, n_j * Tw), odt),
             grid=grid,
             in_specs=in_specs,
-            out_specs=pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY),
+            out_specs=pl.BlockSpec(memory_space=pl.ANY),
             scratch_shapes=[
                 pltpu.VMEM((ext_banks, plan.eh, plan.ew), planes.dtype),
                 pltpu.VMEM((out_banks, S, Tw), odt),
                 pltpu.SemaphoreType.DMA((ext_banks,)),
                 pltpu.SemaphoreType.DMA((out_banks,))],
             interpret=False,
-            compiler_params=CompilerParams(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary",
                                      "arbitrary")),
             name=f"filter2d_halo_fixture_{bug}",

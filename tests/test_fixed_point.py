@@ -226,11 +226,11 @@ def test_read_bytes_per_pixel_is_dtype_aware():
     """The read-once claim restated in bytes: an int8 plan reads ≤ ~1.1
     bytes of HBM per pixel where the same float32 plan reads 4× that —
     the paper's narrow-wordlength throughput multiplier, asserted from
-    the static plan."""
+    the static plan (full-width tiles, as the derived geometry picks)."""
     spec = BorderSpec("mirror")
-    p8 = make_plan(2160, 3840, 5, spec, 128, 512, dtype=np.int8)
-    p16 = make_plan(2160, 3840, 5, spec, 128, 512, dtype=np.int16)
-    p32 = make_plan(2160, 3840, 5, spec, 128, 512, dtype=np.float32)
+    p8 = make_plan(2160, 3840, 5, spec, 256, 3840, dtype=np.int8)
+    p16 = make_plan(2160, 3840, 5, spec, 256, 3840, dtype=np.int16)
+    p32 = make_plan(2160, 3840, 5, spec, 256, 3840, dtype=np.float32)
     b8, b16, b32 = map(read_bytes_per_pixel, (p8, p16, p32))
     assert b8 <= 1.1
     assert abs(b16 - 2 * b8) < 1e-9 and abs(b32 - 4 * b8) < 1e-9

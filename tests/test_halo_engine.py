@@ -15,7 +15,7 @@ from repro.core.border_spec import BorderSpec, np_pad_mode
 from repro.core.filter2d import filter_bank
 from repro.kernels.filter2d import (filter2d_pallas, filter_bank_pallas,
                                     make_plan, read_amplification)
-from repro.kernels.filter2d.halo import _axis_plan
+from repro.kernels.filter2d.halo import LANE, SUBLANE, _axis_plan
 from repro.kernels.filter2d.ops import _filter2d_pallas_planes
 
 TOL = dict(rtol=3e-4, atol=3e-4)
@@ -50,32 +50,43 @@ def np_filter(x, k, policy, c=0.0):
 def test_axis_plan_serves_every_valid_output(L, B, r, same_size):
     """Property: for every block, every un-cropped output's 2r+1-tap window
     resolves to a scratch slot that is either DMA'd in-frame data or a
-    head/tail halo slot the mux fills."""
+    head/tail halo slot the mux fills — and every DMA moves whole sublane
+    tiles between tile-aligned offsets (what Mosaic accepts)."""
     if not same_size and L <= 2 * r:
         pytest.skip("no valid neglect output")
-    ax = _axis_plan(L, B, r, same_size)
+    al = SUBLANE
+    ax = _axis_plan(L, B, r, same_size, al)
     out_extent = L if same_size else L - 2 * r
     by_idx = {c.index: c for c in ax.specials}
     for i in range(ax.n):
+        a = i * B - ax.lead               # scratch slot 0 ≡ frame element a
+        assert a % al == 0 and ax.window % al == 0
         c = by_idx.get(i)
-        if c is None:                     # interior: fully in-frame
-            a = i * B - ax.off
-            assert a >= 0 and a + B + 2 * r <= L
+        if c is None:                     # interior: a full window in-span
+            assert a >= 0 and a + ax.window <= ax.span
             continue
-        lo, hi = c.dst0 - c.head, c.dst0 + c.size + c.tail
+        assert c.src0 % al == 0 and c.dst0 % al == 0 and c.size % al == 0
+        assert c.dst0 == c.src0 - a and c.src0 + c.size <= ax.span
+        lo, hi = c.dst0 - c.head, c.fend + c.tail
         for o in range(min(B, out_extent - i * B)):   # valid outputs only
-            assert lo <= o and o + 2 * r < hi, (i, o, c)
-        # head/tail slots map to frame elements just outside the frame
+            first = ax.shift + o
+            assert lo <= first and first + 2 * r < hi, (i, o, c)
+            assert first + 2 * r < ax.window
+        # head/tail slots map to frame elements just outside the frame,
+        # and the in-frame slots between them were DMA'd
         assert c.head <= r and c.tail <= r
+        assert c.fend == L - a and max(lo, c.dst0) >= c.dst0
         if c.head:
             assert c.src0 == 0            # head implies the top/left edge
         if c.tail:
-            assert c.src0 + c.size == L   # tail implies the bottom/right
+            assert c.fend <= c.dst0 + c.size   # frame end was DMA'd
 
 
 def test_read_amplification_is_about_one():
     """Cost analysis of the read-once claim: HBM elements DMA'd per frame
-    stay within the 2r strip/tile overlap of 1× for every policy."""
+    stay within the aligned strip/tile overlap of 1× for every policy —
+    each window over-fetches whole tiles, round_up(r, 8) rows and
+    round_up(r, 128) columns a side, never more."""
     for pol in ("mirror", "constant", "wrap", "neglect"):
         for H, W, S, T, w in [(2160, 7680, 128, 512, 5), (70, 300, 16, 128, 7),
                               (480, 640, 128, 640, 3)]:
@@ -83,20 +94,32 @@ def test_read_amplification_is_about_one():
                              T + (-T) % 128)
             amp = read_amplification(plan)
             r = (w - 1) // 2
-            bound = (1 + 2 * r / S) * (1 + 2 * r / T) + 0.1
+            off = 0 if pol == "neglect" else r
+
+            def axis(L, B, al):
+                # n aligned windows (the tile-rounded lead a side), plus
+                # wrap's two opposite-edge bands (≤ lead + one tile each)
+                n = -(-(L - 2 * r + 2 * off) // B)
+                lead = -(-off // al) * al
+                win = -(-(lead - off + B + 2 * r) // al) * al
+                band = 2 * (lead + al) if pol == "wrap" else 0
+                return (n * win + band) / L
+
+            bound = axis(H, S, SUBLANE) * axis(W, T, LANE) + 0.1
             assert 0.9 <= amp <= bound, (pol, H, W, amp, bound)
 
 
 def test_stream_is_read_once_no_prematerialized_layout():
     """The tentpole deletion, asserted structurally: the kernel's frame
-    operand is exactly the un-tiled [M, H, W] planes (≈1× frame bytes), and
-    NO intermediate in the traced graph exceeds ~1.4× the frame — the old
-    row-extended, halo-duplicated staging layout (≥2.5× for this geometry)
-    cannot hide anywhere."""
+    operand is exactly the un-tiled [M, H, W] planes, zero-padded to whole
+    (8, 128) tiles (≈1× frame bytes), and NO intermediate in the traced
+    graph exceeds ~1.4× the frame — the old row-extended, halo-duplicated
+    staging layout (≥2.5× for this geometry) cannot hide anywhere."""
     M, H, W = 1, 128, 300
     planes = jax.ShapeDtypeStruct((M, H, W), jnp.float32)
     coeffs = jax.ShapeDtypeStruct((1, 5, 5), jnp.float32)
     frame_elems = M * H * W
+    span_elems = M * H * (W + (-W) % LANE)
     for pol in ("mirror", "wrap", "constant"):
         fn = functools.partial(
             _filter2d_pallas_planes, form="direct", border=BorderSpec(pol),
@@ -114,8 +137,8 @@ def test_stream_is_read_once_no_prematerialized_layout():
                  if eqn.primitive.name != "pallas_call"
                  for v in eqn.outvars if v.aval.shape]
         assert kernel_in, "no pallas_call in the traced graph"
-        # the kernel reads the raw planes (1x) + the w² coefficients
-        assert max(kernel_in) == frame_elems, (pol, kernel_in)
+        # the kernel reads the tile-padded planes + the w² coefficients
+        assert max(kernel_in) == span_elems, (pol, kernel_in)
         # nothing frame-shaped is staged beyond lane/strip padding
         assert max(sizes) <= 1.4 * frame_elems, (pol, max(sizes))
 

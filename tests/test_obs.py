@@ -18,6 +18,7 @@ Acceptance pins:
 import json
 import threading
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -267,7 +268,7 @@ def test_auto_select_event_rules():
 def test_plan_event_candidate_scan():
     obs.enable()
     spec = Filter2D(window=9, dtype="int8", num_filters=2)
-    cf = spec.compile((1024, 4104), "auto", vmem_budget=128 * 1024)
+    cf = spec.compile((1024, 4104), "auto", vmem_budget=4 * 2 ** 20)
     assert cf.execution == "pallas" and cf.regime == "stream"
     pe = obs.events.events(kind="plan")[-1]
     assert (pe.strip_h, pe.tile_w) == (cf.strip_h, cf.tile_w)
@@ -329,16 +330,29 @@ def test_explain_dict_agrees_with_accounting_exactly():
 
 
 def test_explain_roofline_from_shared_constants():
+    """explain() states the roofline of the device it runs on, from the
+    keyed peak table only: a device without published peaks (the CPU
+    here) gets no prediction, and the v5e entry gives the two-ceiling
+    rate for the same per-pixel costs."""
     _, cf = _pipeline()
     d = cf.explain(as_dict=True)
     roof = d["roofline"]
     w = cf.spec.window
     assert roof["flops_per_pixel"] == 2.0 * w * w          # direct, N=1
-    assert roof["peak_flops"] == obs.roofline.PEAK_FLOPS
-    expect = min(obs.roofline.PEAK_FLOPS / roof["flops_per_pixel"],
-                 obs.roofline.HBM_BW / d["hbm"]["bytes_per_pixel"])
-    assert roof["predicted_pixels_per_s"] == pytest.approx(expect)
-    assert roof["bound"] in ("compute", "memory")
+    assert roof["device_kind"] == jax.devices()[0].device_kind
+    assert obs.roofline.peaks(roof["device_kind"]) is None
+    assert roof["predicted_pixels_per_s"] is None and roof["bound"] is None
+    assert "no roofline prediction" in cf.explain()
+    v5e = obs.roofline.PEAKS["TPU v5 lite"]
+    assert v5e == {"bf16_flops": 197e12, "int8_ops": 393e12,
+                   "hbm_bw": 819e9}
+    on = obs.roofline.predicted_pixel_rate(
+        roof["flops_per_pixel"], d["hbm"]["bytes_per_pixel"], "TPU v5 lite")
+    assert on["peak_flops"] == v5e["bf16_flops"]
+    expect = min(v5e["bf16_flops"] / roof["flops_per_pixel"],
+                 v5e["hbm_bw"] / d["hbm"]["bytes_per_pixel"])
+    assert on["predicted_pixels_per_s"] == pytest.approx(expect)
+    assert on["bound"] in ("compute", "memory")
 
 
 def test_explain_text_report_and_repr():

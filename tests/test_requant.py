@@ -275,22 +275,25 @@ def test_round_trip_bytes_close_the_bus():
     """The acceptance pin: an int8→int8 plan moves ≤2.2 HBM bytes/pixel
     round trip (read amplification × 1 byte + 1 byte written), where the
     pre-epilogue datapath paid ≈5 — asserted from the plan, not timed.
-    int16→int16 halves the old 6.1 to ≈4.1 the same way."""
+    int16→int16 halves the old 6.1 to ≈4.1 the same way. (Full-width
+    tiles: the aligned column halo is a whole 128-lane tile a side, so a
+    512-wide tile alone would re-read half its width.)"""
     spec = BorderSpec("mirror")
+    S, T = 256, 3840
     rq8 = RequantSpec(multiplier=1, shift=8, dtype="int8")
-    p8 = make_plan(2160, 3840, 5, spec, 128, 512, dtype=np.int8, requant=rq8)
+    p8 = make_plan(2160, 3840, 5, spec, S, T, dtype=np.int8, requant=rq8)
     assert hbm_write_bytes_per_pixel(p8) == 1.0
     assert hbm_bytes_per_pixel(p8) <= 2.2
-    p8_wide = make_plan(2160, 3840, 5, spec, 128, 512, dtype=np.int8)
+    p8_wide = make_plan(2160, 3840, 5, spec, S, T, dtype=np.int8)
     assert hbm_write_bytes_per_pixel(p8_wide) == 4.0
     assert hbm_bytes_per_pixel(p8_wide) - hbm_bytes_per_pixel(p8) == 3.0
     rq16 = RequantSpec(multiplier=1, shift=8, dtype="int16")
-    p16 = make_plan(2160, 3840, 5, spec, 128, 512, dtype=np.int16,
+    p16 = make_plan(2160, 3840, 5, spec, S, T, dtype=np.int16,
                     requant=rq16)
     assert hbm_write_bytes_per_pixel(p16) == 2.0
     assert hbm_bytes_per_pixel(p16) <= 4.4
     # float plans: write side at the frame's own width, requant rejected
-    pf = make_plan(2160, 3840, 5, spec, 128, 512, dtype=np.float32)
+    pf = make_plan(2160, 3840, 5, spec, S, T, dtype=np.float32)
     assert hbm_write_bytes_per_pixel(pf) == 4.0
 
 

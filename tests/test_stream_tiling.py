@@ -11,7 +11,7 @@ from repro.core.borders import BorderSpec
 from repro.core.filter2d import filter2d, filter_bank
 from repro.kernels.filter2d import (filter2d_pallas, filter_bank_pallas,
                                     stream_vmem_working_set)
-from repro.kernels.filter2d.kernel import LANE
+from repro.kernels.filter2d.halo import LANE, SUBLANE
 
 
 @pytest.mark.parametrize("strip_h", [8, 32, 128])
@@ -67,14 +67,17 @@ def test_8k_frame_bounded_vmem_working_set(rng):
     np.testing.assert_allclose(np.asarray(got), want, rtol=2e-4, atol=2e-4)
 
     # working set: frame-size independent by construction (no frame args),
-    # and bounded by a small multiple of strip_h × tile_w.
+    # and bounded by strip_h × tile_w times the per-pixel terms.
     ws = stream_vmem_working_set(strip_h, tile_w, w)
     dtype_bytes = 4
-    # 2 input-side tiles (strip + carried line buffer) + 1 output tile,
-    # each at most (tile_w + 2r lane-rounded) wide, + the coefficient file.
-    bound = (3 * strip_h * (tile_w + LANE) + w * w) * dtype_bytes
+    # the halo window (one aligned tile of margin a side) at storage and
+    # at accumulator width, one shifted slice per tap over the window's
+    # rows, the output tile + accumulator, and the coefficient file.
+    win_h, win_w = strip_h + 2 * SUBLANE, tile_w + 2 * LANE
+    bound = ((2 * win_h * win_w + w * w * win_h * tile_w
+              + 2 * strip_h * tile_w + w * w) * dtype_bytes)
     assert ws <= bound, (ws, bound)
-    assert ws < 16 * 2 ** 20             # fits one core's VMEM many times
+    assert ws < 16 * 2 ** 20             # fits one core's VMEM
     # the SAME budget serves a frame 256x smaller: no frame term anywhere
     small = jnp.asarray(x[:270, :960])
     got_small = filter2d_pallas(small, jnp.asarray(k), regime="stream",
