@@ -1,0 +1,10 @@
+"""The benchmark's tests run on the CPU at small sizes; the program under
+test is imported from the checkout's ``src``."""
+import os
+import sys
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+for p in (ROOT, os.path.join(ROOT, "src")):
+    if p not in sys.path:
+        sys.path.insert(0, p)
