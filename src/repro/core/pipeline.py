@@ -375,8 +375,7 @@ class CompiledFilter:
             with jax.named_scope(scope):
                 return impl(*call_args)
 
-        with obs_profiler.annotate("repro.pipeline.compile"):
-            self._fn = jax.jit(scoped)
+        self._fn = jax.jit(scoped)
 
         # one plane = H*W pixels; batch/channel planes all stream through
         # the same compiled grid, so the per-call pixel count scales by M
@@ -596,6 +595,21 @@ class CompiledFilter:
     # -- execution ---------------------------------------------------------
 
     def __call__(self, frame, coeffs, gains=None):
+        # the default path: nothing recording and no capture asked for —
+        # one attribute test and one profiler branch, then straight into
+        # the jitted executable, with no span opened
+        if self.profile_dump is None and not obs_profiler.recording():
+            return self._fn(*self._operands(frame, coeffs, gains))
+        with obs_profiler.span("repro.call.operands"):
+            args = self._operands(frame, coeffs, gains)
+        if obs_events._TRACE is None and self.profile_dump is None:
+            return self._launch(args)
+        return self._instrumented_call(args)
+
+    def _operands(self, frame, coeffs, gains):
+        """The executable's operands: the frame checked against the
+        compiled geometry, coefficients and gains normalised (and, for
+        host values, uploaded)."""
         if tuple(frame.shape) != self.frame_shape:
             raise ValueError(
                 f"pipeline compiled for frame shape {self.frame_shape}; "
@@ -609,14 +623,21 @@ class CompiledFilter:
             if gains is not None:
                 raise ValueError("gains supplied but the spec carries no "
                                  "requant epilogue")
-            args = (frame, co)
-        else:
-            args = (frame, co, self._gain_operand(gains))
-        # the default path: one attribute test, then straight into the
-        # jitted executable — observability off costs a single branch
-        if obs_events._TRACE is None and self.profile_dump is None:
-            return self._fn(*args)
-        return self._instrumented_call(args)
+            return (frame, co)
+        return (frame, co, self._gain_operand(gains))
+
+    def _launch(self, args):
+        """The executable's dispatch, up to the returned future (the
+        device work itself is on the trace's device planes). The span
+        carries ``compiled=1`` when the call grew the jit cache."""
+        with obs_profiler.span("repro.call.launch") as s:
+            if s is None:
+                return self._fn(*args)
+            size0 = self._fn._cache_size()
+            y = self._fn(*args)
+            if self._fn._cache_size() > size0:
+                s.set(compiled=1)
+            return y
 
     def _instrumented_call(self, args):
         """Timed execution: wall time via ``block_until_ready``, recompile
@@ -631,8 +652,7 @@ class CompiledFilter:
         size0 = self._fn._cache_size()
         t0 = time.perf_counter()
         with obs_profiler.profile_dump(dump):
-            with obs_profiler.annotate("repro.pipeline.call"):
-                y = jax.block_until_ready(self._fn(*args))
+            y = jax.block_until_ready(self._launch(args))
         wall_s = time.perf_counter() - t0
         size1 = self._fn._cache_size()
         if obs_events._TRACE is not None:

@@ -7,9 +7,15 @@ Zero-overhead-when-off instrumentation of the whole pipeline stack:
     scans), ``execution='auto'`` selections, compiles and per-call
     executions (wall time, pixels/s, cache hit vs recompile) land as
     typed events in a bounded ring and, optionally, a JSONL sink; call
-    latencies land in the process-wide :data:`metrics.REGISTRY`; the
-    plan/compile/call phases get ``jax.profiler`` trace annotations.
+    latencies land in the process-wide :data:`metrics.REGISTRY`.
     Off (the default): every hook is a single attribute-test branch.
+  * ``obs.span(name, **meta)`` — the one span primitive. Recording while
+    a ``jax.profiler`` session collects or the switch is on: a
+    ``TraceAnnotation`` on the profiler's host plane plus a
+    ``span/<name>`` µs histogram in ``REGISTRY``. The filter call has
+    ``repro.call.operands`` / ``repro.call.launch``, the serving engine
+    ``repro.serve.admit`` / ``repro.serve.copy_out``. Not recording: one
+    attribute test and one ``is_enabled()`` branch.
   * ``CompiledFilter.explain()`` — the queryable plan report built on the
     same accounting (see ``core/pipeline.py``).
   * ``obs.roofline`` — the peak constants + two-ceiling roofline model
@@ -29,11 +35,11 @@ from repro.obs.events import (AutoSelectEvent, CompileEvent, ExecuteEvent,
                               PlanEvent, ServeWaveEvent, Trace, disable,
                               emit, enable, enabled, get_trace, tracing)
 from repro.obs.metrics import REGISTRY
-from repro.obs.profiler import annotate, profile_dump
+from repro.obs.profiler import profile_dump, recording, span
 
 __all__ = [
     "AutoSelectEvent", "CompileEvent", "ExecuteEvent", "PlanEvent",
-    "REGISTRY", "ServeWaveEvent", "Trace", "annotate", "disable", "emit",
-    "enable", "enabled", "events", "get_trace", "metrics", "profile_dump",
-    "roofline", "tracing",
+    "REGISTRY", "ServeWaveEvent", "Trace", "disable", "emit", "enable",
+    "enabled", "events", "get_trace", "metrics", "profile_dump",
+    "recording", "roofline", "span", "tracing",
 ]

@@ -32,8 +32,14 @@ executables, background result threads):
     submitters never block on the device at all.
 
 Instrumentation: the engine keeps its own always-on counters
-(:meth:`FilterServeEngine.stats`) and, when ``repro.obs`` tracing is on,
-mirrors them into ``obs.REGISTRY`` (counters ``serve.requests``,
+(:meth:`FilterServeEngine.stats`, among them ``h2d_bytes``, the host
+frames copied in, and ``d2h_bytes``, the results copied out with their
+padding). Each wave opens two ``obs.span``s, ``repro.serve.admit``
+(copy-in, stack, padding) and ``repro.serve.copy_out`` (the wait for the
+device and the copy back), with the wave's number and its requests' ids
+as metadata; they record while a profiler session collects or
+``repro.obs`` is on. When ``repro.obs`` tracing is on, the engine also
+mirrors its counters into ``obs.REGISTRY`` (``serve.requests``,
 ``serve.waves``, ``serve.cache_hits``, ``serve.recompiles``,
 ``serve.evictions``, ``serve.pixels``, ``serve.errors``,
 ``serve.cancelled``; histograms ``serve/request_us``, ``serve/wave_us``,
@@ -50,6 +56,7 @@ import time
 from collections import OrderedDict, deque
 from typing import Callable, List, Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -58,6 +65,15 @@ from repro.core.pipeline import (Filter2D, admit_batch, batched_shape,
 from repro.core.requant import RequantSpec
 from repro.obs import events as obs_events
 from repro.obs import metrics as obs_metrics
+from repro.obs import profiler as obs_profiler
+
+
+def _wave_meta(seq: int, wave) -> dict:
+    """A wave's span metadata (its dispatch number and its riders' ids),
+    built only while spans record."""
+    if not obs_profiler.recording():
+        return {}
+    return {"wave": seq, "requests": " ".join(str(r.rid) for r in wave)}
 
 
 def _operand_digest(x):
@@ -179,7 +195,9 @@ class FilterServeEngine:
             "requests": 0, "completed": 0, "waves": 0, "cache_hits": 0,
             "recompiles": 0, "evictions": 0, "pixels": 0,
             "padded_planes": 0, "errors": 0, "cancelled": 0,
+            "h2d_bytes": 0, "d2h_bytes": 0,
         }
+        self._dispatched = 0
         self._worker = threading.Thread(target=self._run, daemon=True,
                                         name="filter-serve-worker")
         self._worker.start()
@@ -349,22 +367,30 @@ class FilterServeEngine:
         pipe, hit = self._get_pipeline(key, wave[0])
         if hit and obs_events.enabled():
             obs_metrics.REGISTRY.counter("serve.cache_hits").inc()
+        self._dispatched += 1
+        seq = self._dispatched
         t0 = time.perf_counter()
         for r in wave:
             r.admit_t = t0
-        x = admit_batch([r.frame for r in wave], self.batch_size)
+        with obs_profiler.span("repro.serve.admit", **_wave_meta(seq, wave)):
+            x = admit_batch([r.frame for r in wave], self.batch_size)
+        # frames already on the device are not copied in
+        h2d = sum(int(r.frame.nbytes) for r in wave
+                  if not isinstance(r.frame, jax.Array))
         head = wave[0]
         if head.gains is not None:
             y = pipe(x, head.coeffs, gains=head.gains)
         else:
             y = pipe(x, head.coeffs)
-        return key, wave, y, t0, hit, depth
+        return key, wave, y, t0, hit, depth, seq, h2d
 
     def _complete(self, inflight) -> None:
         """Copy one wave's results out (blocks until the device is done),
         split them back per request, and wake the waiters."""
-        key, wave, y, t0, hit, depth = inflight
-        y = np.asarray(y)
+        key, wave, y, t0, hit, depth, seq, h2d = inflight
+        with obs_profiler.span("repro.serve.copy_out",
+                               **_wave_meta(seq, wave)):
+            y = np.asarray(y)
         now = time.perf_counter()
         wall_s = max(now - t0, 1e-9)
         outs = split_batch(y, len(wave), len(wave[0].frame.shape))
@@ -381,6 +407,8 @@ class FilterServeEngine:
             self._stats["waves"] += 1
             self._stats["pixels"] += pixels
             self._stats["padded_planes"] += padded
+            self._stats["h2d_bytes"] += h2d
+            self._stats["d2h_bytes"] += y.nbytes
             self._work.notify_all()
         if obs_events.enabled():
             reg = obs_metrics.REGISTRY

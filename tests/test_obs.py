@@ -239,8 +239,10 @@ def test_compile_and_execute_events(rng):
     assert counters["pipeline.calls"] == 2
     assert counters["pipeline.cache_hits"] == 1
     hists = obs.REGISTRY.histograms()
-    [(name, h)] = list(hists.items())
-    assert name.startswith("call/pallas/stream/") and h.count == 2
+    assert set(hists) == {f"call/{cf._obs_key}", "span/repro.call.operands",
+                          "span/repro.call.launch"}
+    assert cf._obs_key.startswith("pallas/stream/")
+    assert all(h.count == 2 for h in hists.values())
 
 
 def test_auto_select_event_rules():
@@ -384,12 +386,106 @@ def test_explain_without_plan():
 # ---------------------------------------------------------------------------
 
 
-def test_off_means_no_registry_traffic(rng):
+def test_off_means_no_registry_traffic(rng, monkeypatch):
+    """Off and with no profiler session, a call — the first one traces
+    and compiles — opens no span: no annotation is built, no metadata
+    formatted, nothing recorded."""
+    def no_annotation(*a, **k):
+        raise AssertionError("a span was opened with nothing recording")
+
+    monkeypatch.setattr(obs.profiler, "TraceAnnotation", no_annotation)
     spec = Filter2D(window=5)
     cf = spec.compile((EH, EW + 32), "pallas", regime="stream",
                       strip_h=12, tile_w=128)
     x = jnp.asarray(rng.standard_normal((EH, EW + 32)).astype(np.float32))
+    assert not obs.recording()
     cf(x, jnp.asarray(filters.gaussian(5)))
+    cf(x, jnp.asarray(filters.gaussian(5)))
+    assert cf.cache_size() == 1
     assert obs.REGISTRY.counters() == {}
     assert obs.REGISTRY.histograms() == {}
     assert obs.get_trace() is None
+
+
+class _Unformattable:
+    def __str__(self):
+        raise AssertionError("span metadata formatted while off")
+
+    __repr__ = __str__
+
+
+def test_span_off_is_a_shared_no_op():
+    with obs.span("repro.test.off", meta=_Unformattable()) as s:
+        assert s is None
+    assert obs.span("a") is obs.span("b")
+    assert obs.REGISTRY.histograms() == {}
+
+
+def test_span_records_its_duration_when_obs_is_on():
+    obs.enable()
+    assert obs.recording()
+    for _ in range(3):
+        with obs.span("repro.test.on", wave=1) as s:
+            assert s is not None
+            s.set(requests="1 2")
+    [(name, h)] = list(obs.REGISTRY.histograms().items())
+    assert name == "span/repro.test.on" and h.count == 3
+    assert h.summary()["min"] >= 0
+
+
+# ---------------------------------------------------------------------------
+# Spans on the profiler's host plane
+# ---------------------------------------------------------------------------
+
+
+def test_call_spans_land_on_the_profiler_host_plane(tmp_path, rng):
+    """With ``obs`` off, a profiler session alone makes the call's spans
+    record: they sit on a ``/host:`` plane inside the session's window,
+    on the trace's clock, and in ``span/<name>`` histograms."""
+    from bench import trace_reduce
+
+    spec = Filter2D(window=3, dtype="int8")
+    cf = spec.compile((16, 24), "core")
+    x = jnp.asarray(rng.integers(-8, 8, (16, 24)).astype(np.int8))
+    k = jnp.ones((3, 3), jnp.int8)
+    cf(x, k)                                   # compiled before the window
+    assert not obs.recording()
+    with jax.profiler.trace(str(tmp_path)):
+        assert obs.recording()
+        with jax.profiler.TraceAnnotation("bench.window"):
+            jax.block_until_ready([cf(x, k) for _ in range(3)])
+    assert not obs.recording()
+    [pb] = list(tmp_path.rglob("*.xplane.pb"))
+    trace = trace_reduce.load_xspace(str(pb), host_prefix="repro.")
+    names = [n for _, _, n in trace.host]
+    assert names.count("repro.call.operands") == 3
+    assert names.count("repro.call.launch") == 3
+    lo, hi = trace_reduce.load_xspace(str(pb)).window()
+    assert all(lo <= s <= e <= hi for s, e, _ in trace.host)
+    # leaves, one after the other: operands then launch, per call
+    spans = sorted(trace.host)
+    assert [n for _, _, n in spans] == ["repro.call.operands",
+                                        "repro.call.launch"] * 3
+    assert all(a[1] <= b[0] for a, b in zip(spans, spans[1:]))
+    hists = obs.REGISTRY.histograms()
+    assert {n: h.count for n, h in hists.items()} == {
+        "span/repro.call.operands": 3, "span/repro.call.launch": 3}
+
+
+def test_launch_span_marks_the_call_that_compiled(tmp_path, rng):
+    from jax.profiler import ProfileData
+
+    spec = Filter2D(window=3, dtype="int8")
+    cf = spec.compile((16, 40), "core")
+    x = jnp.asarray(rng.integers(-8, 8, (16, 40)).astype(np.int8))
+    k = jnp.ones((3, 3), jnp.int8)
+    with jax.profiler.trace(str(tmp_path)):
+        jax.block_until_ready([cf(x, k) for _ in range(2)])
+    [pb] = list(tmp_path.rglob("*.xplane.pb"))
+    launches = sorted(
+        (e.start_ns, dict(e.stats))
+        for p in ProfileData.from_file(str(pb)).planes
+        if p.name.startswith("/host:") for line in p.lines
+        for e in line.events if e.name == "repro.call.launch")
+    assert [m.get("compiled") for _, m in launches] == [1, None]
+

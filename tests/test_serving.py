@@ -283,6 +283,83 @@ def test_concurrent_submitters():
                                        np.asarray(r.frame) * (t + 2))
 
 
+def test_copy_byte_counters_are_exact():
+    """A 3-frame wave at batch 4: ``h2d_bytes`` counts the three host
+    frames copied in, ``d2h_bytes`` the four planes copied out (the pad
+    plane too). A frame already on the device is not copied in."""
+    import jax.numpy as jnp
+
+    gate, entered = threading.Event(), threading.Event()
+
+    def compile_fn(spec, shape):
+        def pipe(x, coeffs, gains=None):
+            if float(np.asarray(coeffs).flat[0]) == 5.0:   # the blocker
+                entered.set()
+                assert gate.wait(30)
+            return np.asarray(x) * 2
+        return pipe
+
+    blocker = frame(8, 8)                          # float32, 256 B
+    with FilterServeEngine(batch_size=4, compile_fn=compile_fn) as eng:
+        eng.submit(blocker, K2, spec=SPEC3)
+        assert entered.wait(30)
+        # the worker is held inside the blocker's dispatch: these three
+        # queue up behind it and leave as one wave
+        wave = [eng.submit(frame(6, 10, seed=i), K1, spec=SPEC3)
+                for i in range(3)]
+        gate.set()
+        assert eng.drain(timeout=30)
+        st = eng.stats()
+        assert st["waves"] == 2 and st["padded_planes"] == 3 + 1
+        assert st["h2d_bytes"] == blocker.nbytes + 3 * 6 * 10 * 4
+        assert st["d2h_bytes"] == 4 * blocker.nbytes + 4 * 6 * 10 * 4
+        for r in wave:
+            np.testing.assert_array_equal(r.result(), np.asarray(r.frame) * 2)
+
+        on_device = jnp.asarray(frame(6, 10))
+        eng.submit(on_device, K1, spec=SPEC3).result(timeout=30)
+        after = eng.stats()
+    assert after["h2d_bytes"] == st["h2d_bytes"]
+    assert after["d2h_bytes"] == st["d2h_bytes"] + 4 * 6 * 10 * 4
+
+
+def test_wave_spans_carry_the_wave_and_its_requests(tmp_path):
+    """Under a profiler session each wave's ``repro.serve.admit`` and
+    ``repro.serve.copy_out`` spans are on the host plane with the wave's
+    dispatch number and its requests' ids, one of each per wave."""
+    import jax
+    from jax.profiler import ProfileData
+
+    obs.REGISTRY.reset()
+    fx = FakeExecutor()
+    with jax.profiler.trace(str(tmp_path)):
+        with FilterServeEngine(batch_size=2,
+                               compile_fn=fx.compile_fn) as eng:
+            reqs = [eng.submit(frame(8, 8, seed=i), K1, spec=SPEC3)
+                    for i in range(3)]
+            assert eng.drain(timeout=30)
+            waves = eng.stats()["waves"]
+    [pb] = list(tmp_path.rglob("*.xplane.pb"))
+    spans = {"repro.serve.admit": [], "repro.serve.copy_out": []}
+    for p in ProfileData.from_file(str(pb)).planes:
+        if p.name.startswith("/host:"):
+            for line in p.lines:
+                for e in line.events:
+                    if e.name in spans:
+                        spans[e.name].append(dict(e.stats))
+    for name, metas in spans.items():
+        assert sorted(m["wave"] for m in metas) == list(
+            range(1, waves + 1)), name
+        # a lone id reads back as an int
+        rids = sorted(int(r) for m in metas
+                      for r in str(m["requests"]).split())
+        assert rids == sorted(r.rid for r in reqs), name
+    counts = {n: h.count for n, h in obs.REGISTRY.histograms().items()}
+    assert counts == {"span/repro.serve.admit": waves,
+                      "span/repro.serve.copy_out": waves}
+    obs.REGISTRY.reset()
+
+
 def test_engine_off_means_no_registry_traffic():
     """With obs tracing off, serving leaves obs.REGISTRY untouched (the
     engine's always-on stats live in engine.stats() only)."""
