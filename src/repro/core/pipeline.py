@@ -30,14 +30,18 @@ path; ``compile`` results are memoised so the wrappers stay cheap.
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
 import functools
 import hashlib
+import threading
 import time
 from typing import List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.border_spec import BorderSpec, quantize_constant
 from repro.core.filter2d import (FORMS, _filter2d_impl, _filter2d_sep_impl,
@@ -57,6 +61,10 @@ from repro.obs import roofline as obs_roofline
 DEFAULT_VMEM_BUDGET = halo.DEFAULT_VMEM_BUDGET
 
 EXECUTIONS = ("auto", "core", "xla", "pallas", "streaming", "sharded")
+
+# distinct gain specs a pipeline keeps on the device; past this, the least
+# recently used one is dropped and uploaded again when it comes back
+GAIN_MEMO_SIZE = 64
 
 
 @dataclasses.dataclass(frozen=True)
@@ -244,6 +252,12 @@ class CompiledFilter:
         self.profile_dump = profile_dump
         self._profiled = False
         self._verify_report = None     # cached by verify()
+        # device-resident gains operands keyed by RequantSpec value, least
+        # recently used first; the lock covers lookup, order and counts
+        self._gain_memo = collections.OrderedDict()
+        self._gain_lock = threading.Lock()
+        self._gain_hits = 0
+        self._gain_uploads = 0
         self.vmem_budget = (DEFAULT_VMEM_BUDGET if vmem_budget is None
                             else int(vmem_budget))
         self.interpret = (ops._default_interpret() if interpret is None
@@ -572,17 +586,34 @@ class CompiledFilter:
                              f"{want}; got {co.shape}")
         return co
 
-    def _gain_operand(self, gains):
+    def _gain_operand(self, gains, reg=None):
+        """The ``[n, 2]`` int32 gains operand. A ``RequantSpec`` (``None``
+        stands for the compiled spec's own) is uploaded on its first call
+        and kept on the device for every later call with an equal spec.
+        ``reg`` is the registry to count hits and uploads in (while
+        recording), or ``None``."""
         rq, n = self.spec.requant, self.spec.num_filters
-        if gains is None:
-            return jnp.asarray(rq.params(n), jnp.int32)
-        if isinstance(gains, RequantSpec):
-            if gains.gain_free() != rq.gain_free():
-                raise ValueError(
-                    "gains spec disagrees with the compiled epilogue "
-                    f"(rounding/storage dtype): {gains.gain_free()} vs "
-                    f"{rq.gain_free()}; recompile for a new epilogue")
-            return jnp.asarray(gains.params(n), jnp.int32)
+        key = rq if gains is None else gains
+        if isinstance(key, RequantSpec):
+            with self._gain_lock:
+                g = self._gain_memo.get(key)
+                uploaded = g is None
+                if uploaded:
+                    g = self._upload_gains(key)
+                    self._gain_memo[key] = g
+                    if len(self._gain_memo) > GAIN_MEMO_SIZE:
+                        self._gain_memo.popitem(last=False)
+                    self._gain_uploads += 1
+                else:
+                    self._gain_memo.move_to_end(key)
+                    self._gain_hits += 1
+            if reg is not None:
+                reg.counter("pipeline.gain_uploads" if uploaded
+                            else "pipeline.gain_hits").inc()
+            return g
+        if (isinstance(gains, jax.Array) and gains.dtype == jnp.int32
+                and gains.shape == (n, 2)):
+            return gains
         g = jnp.asarray(gains, jnp.int32)
         if g.shape == (2,):
             g = jnp.broadcast_to(g[None], (n, 2))
@@ -591,6 +622,21 @@ class CompiledFilter:
                              f"(multiplier, shift) pair or an [{n}, 2] "
                              f"table; got shape {g.shape}")
         return g
+
+    def _upload_gains(self, gains: RequantSpec):
+        """A spec's table on the device — replicated over the mesh when
+        there is one — after checking it against the compiled epilogue."""
+        rq = self.spec.requant
+        if gains.gain_free() != rq.gain_free():
+            raise ValueError(
+                "gains spec disagrees with the compiled epilogue "
+                f"(rounding/storage dtype): {gains.gain_free()} vs "
+                f"{rq.gain_free()}; recompile for a new epilogue")
+        params = gains.params(self.spec.num_filters)
+        if self.mesh is None:
+            return jnp.asarray(params, jnp.int32)
+        return jax.device_put(np.asarray(params, np.int32),
+                              NamedSharding(self.mesh, P()))
 
     # -- execution ---------------------------------------------------------
 
@@ -601,15 +647,16 @@ class CompiledFilter:
         if self.profile_dump is None and not obs_profiler.recording():
             return self._fn(*self._operands(frame, coeffs, gains))
         with obs_profiler.span("repro.call.operands"):
-            args = self._operands(frame, coeffs, gains)
+            args = self._operands(frame, coeffs, gains, obs_metrics.REGISTRY)
         if obs_events._TRACE is None and self.profile_dump is None:
             return self._launch(args)
         return self._instrumented_call(args)
 
-    def _operands(self, frame, coeffs, gains):
+    def _operands(self, frame, coeffs, gains, reg=None):
         """The executable's operands: the frame checked against the
         compiled geometry, coefficients and gains normalised (and, for
-        host values, uploaded)."""
+        host values, uploaded; gain specs once each, see
+        :meth:`_gain_operand`)."""
         if tuple(frame.shape) != self.frame_shape:
             raise ValueError(
                 f"pipeline compiled for frame shape {self.frame_shape}; "
@@ -624,7 +671,7 @@ class CompiledFilter:
                 raise ValueError("gains supplied but the spec carries no "
                                  "requant epilogue")
             return (frame, co)
-        return (frame, co, self._gain_operand(gains))
+        return (frame, co, self._gain_operand(gains, reg))
 
     def _launch(self, args):
         """The executable's dispatch, up to the returned future (the
@@ -677,6 +724,14 @@ class CompiledFilter:
         call, and *still* 1 after any number of coefficient / factor /
         gain swaps — the served-pipeline invariant tests pin."""
         return self._fn._cache_size()
+
+    def operand_stats(self) -> dict:
+        """How often a call's gains operand was already on the device
+        (``gain_hits``) and how often it was uploaded (``gain_uploads``);
+        raw gains tables count in neither."""
+        with self._gain_lock:
+            return {"gain_hits": self._gain_hits,
+                    "gain_uploads": self._gain_uploads}
 
     def vmem_working_set(self) -> Optional[int]:
         """Per-step VMEM bytes of the planned geometry (from the plan) —

@@ -48,11 +48,14 @@ def _mesh1():
     return jax.make_mesh((1,), ("data",))
 
 
-def _compile(spec, x, execution):
-    kw = {"strip_h": 8, "tile_w": 128}
+def _knobs(execution):
     if execution == "sharded":
-        kw = {"mesh": _mesh1()}
-    return spec.compile(x, execution, **kw)
+        return {"mesh": _mesh1()}
+    return {"strip_h": 8, "tile_w": 128}
+
+
+def _compile(spec, x, execution):
+    return spec.compile(x, execution, **_knobs(execution))
 
 
 # ---------------------------------------------------------------------------
@@ -439,3 +442,235 @@ def test_swaps_zero_recompiles_with_tracing_enabled(rng):
     finally:
         obs.disable()
         obs.REGISTRY.reset()
+
+
+# ---------------------------------------------------------------------------
+# Gains kept on the device: one upload per distinct RequantSpec
+# ---------------------------------------------------------------------------
+
+GAIN_A = RequantSpec(multiplier=3, shift=7, rounding="nearest", dtype="int8")
+GAIN_B = RequantSpec(multiplier=-5, shift=9, rounding="nearest", dtype="int8")
+GAIN_OWN = RequantSpec(multiplier=7, shift=8, rounding="nearest",
+                       dtype="int8")
+
+
+def _fresh(spec, x, execution):
+    """A pipeline built outside the compile memo, so its gains memo and
+    counts start empty whatever other tests compiled."""
+    return CompiledFilter(spec, tuple(x.shape), execution,
+                          **_knobs(execution))
+
+
+@pytest.mark.parametrize("execution", ("core",) + EXECUTORS)
+def test_gain_memo_swap_sequence_is_bit_exact(execution, rng):
+    """a, b, a, None, b: each distinct spec is uploaded once, a hit hands
+    back the very array stored, the executable never grows, and every
+    result equals both an unmemoized pipeline's (raw host tables) and
+    the int64 reference."""
+    x = jnp.asarray(_frame(rng, np.int8))
+    k = jnp.asarray(_kernel(rng, np.int8))
+    spec = Filter2D(window=5, dtype="int8", requant=GAIN_OWN)
+    cf = _fresh(spec, x, execution)
+    plain = _fresh(spec, x, execution)
+    acc = np.asarray(filter2d(x, k))
+    for gains in (GAIN_A, GAIN_B, GAIN_A, None, GAIN_B):
+        rq = GAIN_OWN if gains is None else gains
+        got = np.asarray(cf(x, k) if gains is None else cf(x, k, gains))
+        assert cf.cache_size() == 1
+        want = np.asarray(plain(x, k, np.asarray(rq.params(1), np.int32)))
+        np.testing.assert_array_equal(got, want)
+        np.testing.assert_array_equal(got, requantize_ref(acc, rq))
+    assert cf.operand_stats() == {"gain_hits": 2, "gain_uploads": 3}
+    assert plain.operand_stats() == {"gain_hits": 0, "gain_uploads": 0}
+    first = cf._operands(x, k, GAIN_A)[2]
+    assert cf._operands(x, k, GAIN_A)[2] is first
+    assert cf._operands(x, k, None)[2] is cf._operands(x, k, GAIN_OWN)[2]
+    assert cf.operand_stats()["gain_uploads"] == 3
+    assert cf.cache_size() == 1
+
+
+@pytest.mark.parametrize("bad", [
+    RequantSpec(multiplier=3, shift=7, rounding="truncate", dtype="int8"),
+    RequantSpec(multiplier=3, shift=7, rounding="nearest", dtype="int16"),
+], ids=["rounding", "dtype"])
+def test_gain_memo_never_stores_a_disagreeing_spec(bad, rng):
+    """A spec whose rounding or storage dtype disagrees with the compiled
+    epilogue raises on every call: the first failure stores nothing."""
+    x = jnp.asarray(_frame(rng, np.int8))
+    k = jnp.asarray(_kernel(rng, np.int8))
+    cf = _fresh(Filter2D(window=5, dtype="int8", requant=GAIN_OWN), x,
+                "core")
+    for _ in range(2):
+        with pytest.raises(ValueError, match="disagrees with the compiled"):
+            cf(x, k, gains=bad)
+    assert cf.operand_stats() == {"gain_hits": 0, "gain_uploads": 0}
+    cf(x, k, gains=GAIN_A)
+    assert cf.operand_stats() == {"gain_hits": 0, "gain_uploads": 1}
+
+
+def test_gain_memo_evicts_the_least_recently_used(rng):
+    """Past the fixed bound the least recently used spec is dropped, and
+    uploaded again when it comes back; a recently used one survives."""
+    from repro.core.pipeline import GAIN_MEMO_SIZE
+    x = jnp.asarray(_frame(rng, np.int8))
+    k = jnp.asarray(_kernel(rng, np.int8))
+    cf = _fresh(Filter2D(window=5, dtype="int8", requant=GAIN_OWN), x,
+                "core")
+    specs = [RequantSpec(multiplier=m + 1, shift=7, rounding="nearest",
+                         dtype="int8") for m in range(GAIN_MEMO_SIZE + 1)]
+    for s in specs[:GAIN_MEMO_SIZE]:
+        cf._operands(x, k, s)
+    assert cf.operand_stats() == {"gain_hits": 0,
+                                  "gain_uploads": GAIN_MEMO_SIZE}
+    kept = cf._operands(x, k, specs[0])[2]         # now the most recent
+    cf._operands(x, k, specs[-1])                  # evicts specs[1]
+    assert cf.operand_stats() == {"gain_hits": 1,
+                                  "gain_uploads": GAIN_MEMO_SIZE + 1}
+    assert cf._operands(x, k, specs[0])[2] is kept
+    cf._operands(x, k, specs[1])
+    assert cf.operand_stats() == {"gain_hits": 2,
+                                  "gain_uploads": GAIN_MEMO_SIZE + 2}
+    np.testing.assert_array_equal(
+        np.asarray(cf(x, k, specs[1])),
+        requantize_ref(np.asarray(filter2d(x, k)), specs[1]))
+
+
+def test_gain_memo_is_safe_under_concurrent_callers(rng):
+    """More threads than cores share one pipeline, switching often: no
+    call is lost from the counts, each spec is uploaded once while all
+    fit, the memo never passes its bound, and every operand holds its
+    own spec's gains."""
+    import os
+    import sys
+    import threading
+    from repro.core.pipeline import GAIN_MEMO_SIZE
+    x = jnp.asarray(_frame(rng, np.int8))
+    cf = _fresh(Filter2D(window=5, dtype="int8", requant=GAIN_OWN), x,
+                "core")
+    threads_n = (os.cpu_count() or 4) + 4
+    calls = 200
+    wrong = []
+
+    def hammer(specs, seed):
+        order = np.random.default_rng(seed).integers(0, len(specs), calls)
+        for i in order:
+            g = cf._gain_operand(specs[i])
+            if np.asarray(g).tolist() != [list(specs[i].params(1)[0])]:
+                wrong.append(specs[i])
+
+    def run(specs):
+        ts = [threading.Thread(target=hammer, args=(specs, t))
+              for t in range(threads_n)]
+        for t in ts:
+            t.start()
+        for t in ts:
+            t.join(timeout=120)
+        assert not any(t.is_alive() for t in ts)
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        few = [RequantSpec(multiplier=m + 1, shift=5, rounding="nearest",
+                           dtype="int8") for m in range(16)]
+        run(few)
+        assert cf.operand_stats() == {
+            "gain_hits": threads_n * calls - 16, "gain_uploads": 16}
+        many = [RequantSpec(multiplier=m + 1, shift=6, rounding="nearest",
+                            dtype="int8")
+                for m in range(GAIN_MEMO_SIZE + 16)]
+        run(many)
+    finally:
+        sys.setswitchinterval(interval)
+    st = cf.operand_stats()
+    assert st["gain_hits"] + st["gain_uploads"] == 2 * threads_n * calls
+    assert len(cf._gain_memo) == GAIN_MEMO_SIZE
+    assert not wrong
+
+
+def test_raw_gains_tables_bypass_the_memo(rng):
+    """An int32 device table of shape (n, 2) passes through as it is;
+    host pairs and tables keep their upload; neither is counted."""
+    x = jnp.asarray(_frame(rng, np.int8))
+    k = jnp.asarray(_kernel(rng, np.int8))
+    cf = _fresh(Filter2D(window=5, dtype="int8", requant=GAIN_OWN), x,
+                "core")
+    table = jnp.asarray([[3, 7]], jnp.int32)
+    assert cf._operands(x, k, table)[2] is table
+    pair = cf._operands(x, k, (3, 7))[2]
+    assert pair.shape == (1, 2) and pair.dtype == jnp.int32
+    want = requantize_ref(np.asarray(filter2d(x, k)), GAIN_A)
+    for gains in (table, (3, 7), np.asarray([[3, 7]])):
+        np.testing.assert_array_equal(np.asarray(cf(x, k, gains)), want)
+    assert cf.operand_stats() == {"gain_hits": 0, "gain_uploads": 0}
+
+
+def test_gain_counters_record_only_while_recording(rng):
+    """With nothing recording the calls touch no registry counter; while
+    ``repro.obs`` is on, hits and uploads land in ``pipeline.gain_*``."""
+    from repro import obs
+    x = jnp.asarray(_frame(rng, np.int8))
+    k = jnp.asarray(_kernel(rng, np.int8))
+    cf = _fresh(Filter2D(window=5, dtype="int8", requant=GAIN_OWN), x,
+                "core")
+    obs.disable()
+    obs.REGISTRY.reset()
+    try:
+        for gains in (GAIN_A, GAIN_A, GAIN_B):
+            cf(x, k, gains)
+        assert not [c for c in obs.REGISTRY.counters()
+                    if c.startswith("pipeline.gain")]
+        obs.enable()
+        for gains in (GAIN_A, GAIN_B, None, None):
+            cf(x, k, gains)
+        counters = obs.REGISTRY.counters()
+        assert counters["pipeline.gain_hits"] == 3
+        assert counters["pipeline.gain_uploads"] == 1
+    finally:
+        obs.disable()
+        obs.REGISTRY.reset()
+    assert cf.operand_stats() == {"gain_hits": 4, "gain_uploads": 3}
+
+
+def test_sharded_gains_stay_replicated_over_the_mesh():
+    """On 4 virtual devices the sharded pipeline stores its gains operand
+    replicated over the mesh, reuses it, and its output is unchanged
+    (bit-exact with the single-device filter). Runs in a subprocess:
+    the device count must be set before jax starts."""
+    import os
+    import subprocess
+    import sys
+    import textwrap
+    code = textwrap.dedent("""
+    import numpy as np, jax, jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from repro.core.filter2d import filter2d
+    from repro.core.pipeline import Filter2D
+    from repro.core.requant import RequantSpec
+    assert len(jax.devices()) == 4
+    mesh = jax.make_mesh((4,), ("data",))
+    rng = np.random.default_rng(5)
+    x = jnp.asarray(rng.integers(-20, 20, (1, 64, 40, 1)).astype(np.int8))
+    k = jnp.asarray(rng.integers(-4, 5, (5, 5)).astype(np.int32))
+    a = RequantSpec(multiplier=3, shift=7, rounding="nearest", dtype="int8")
+    b = RequantSpec(multiplier=-5, shift=9, rounding="nearest", dtype="int8")
+    cf = Filter2D(window=5, dtype="int8", requant=a).compile(
+        x, "sharded", mesh=mesh)
+    g = cf._operands(x, k, a)[2]
+    assert g.sharding.is_equivalent_to(NamedSharding(mesh, P()), g.ndim)
+    assert g.sharding.is_fully_replicated and len(g.devices()) == 4
+    assert cf._operands(x, k, a)[2] is g
+    for rq in (a, b, a, None):
+        y = cf(x, k) if rq is None else cf(x, k, rq)
+        ref = filter2d(x, k, requant=a if rq is None else rq)
+        np.testing.assert_array_equal(np.asarray(y), np.asarray(ref))
+    assert cf.cache_size() == 1
+    assert cf.operand_stats()["gain_uploads"] == 2, cf.operand_stats()
+    print("OK")
+    """)
+    env = dict(os.environ)
+    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
+    r = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                       text=True, timeout=300, env=env)
+    assert r.returncode == 0 and "OK" in r.stdout, (
+        f"stdout:\n{r.stdout}\nstderr:\n{r.stderr}")

@@ -419,6 +419,40 @@ def test_real_pipeline_parity_and_warm_contract(rng):
     obs.REGISTRY.reset()
 
 
+def test_tenant_gains_upload_once_per_tenant(rng):
+    """Three tenants, each with its own requant gains, over several waves
+    of one bucket: the shared pipeline uploads each tenant's gains once
+    and every result still equals the int64 reference."""
+    import jax.numpy as jnp
+
+    from repro.core.filter2d import filter2d
+    from repro.core.requant import RequantSpec, requantize_ref
+    spec = Filter2D(window=3, dtype="int8",
+                    requant=RequantSpec(1, 0, rounding="nearest",
+                                        dtype="int8"))
+    gains = [RequantSpec(m, s, rounding="nearest", dtype="int8")
+             for m, s in ((3, 5), (-7, 6), (11, 8))]
+    k = rng.integers(-4, 5, (3, 3)).astype(np.int32)
+    frames = [rng.integers(-20, 20, (10, 14)).astype(np.int8)
+              for _ in range(4)]
+    pipe = spec.compile(batched_shape((10, 14), 2), "core")
+    before = pipe.operand_stats()["gain_uploads"]
+    with FilterServeEngine(batch_size=2, execution="core") as eng:
+        reqs = []
+        for i in range(4):
+            for t, g in enumerate(gains):
+                reqs.append((eng.submit(frames[i], k, spec=spec, gains=g,
+                                        tenant=f"t{t}"), i, g))
+            assert eng.drain(timeout=60)
+        st = eng.stats()
+    assert st["recompiles"] == 1 and st["waves"] >= 4
+    assert pipe.operand_stats()["gain_uploads"] - before == 3
+    for r, i, g in reqs:
+        acc = np.asarray(filter2d(jnp.asarray(frames[i]), jnp.asarray(k)))
+        np.testing.assert_array_equal(r.result(timeout=10),
+                                      requantize_ref(acc, g))
+
+
 def test_bench_smoke_tiny(rng):
     """serving.bench end to end (tiny): rows in the BENCH_* schema, the
     aggregate row reports latency + sustained pixels/s, and the warm
