@@ -14,7 +14,7 @@ must not disturb the (sharded) stream.
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional
+from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
@@ -28,17 +28,32 @@ from repro.core.filter2d import (_FORM_FNS, _as_nhwc, _un_nhwc,
                                  resolve_requant)
 from repro.core.requant import RequantSpec
 
+HALO_SCOPE = "repro.shard.halo"
+
+
+def check_row_split(H: int, n_shards: int, r: int) -> None:
+    """Raise ``ValueError`` unless ``H`` rows split evenly over
+    ``n_shards`` shards that each hold at least the ``r`` halo rows their
+    neighbours need."""
+    if H % n_shards or H // n_shards < r:
+        raise ValueError(
+            f"a frame of {H} rows cannot be row-sharded over {n_shards} "
+            f"shards with a halo of r={r} rows: the rows must split evenly "
+            "and each shard must hold at least r of them")
+
 
 def _filter2d_sharded_impl(frame: jax.Array, coeffs: jax.Array, mesh: Mesh,
                            q_params: Optional[jax.Array] = None,
                            *, axis: str = "data", form: str = "direct",
                            border_policy: str = "mirror",
                            border: Optional[BorderSpec] = None,
-                           requant: Optional[RequantSpec] = None
-                           ) -> jax.Array:
+                           requant: Optional[RequantSpec] = None,
+                           on_halo_bytes: Optional[Callable[[int], None]]
+                           = None) -> jax.Array:
     """Row-shard ``frame`` over ``mesh[axis]`` and filter with halo exchange.
 
-    frame: [B,H,W,C] (H divisible by the axis size). Returns same shape.
+    frame: [B,H,W,C] (H split as :func:`check_row_split` allows, which the
+    caller checks). Returns same shape.
     Every same-size policy is supported: ``wrap`` in particular is *free*
     here — the ppermute halo exchange already runs on a ring, so the first
     shard's top halo arrives from the last shard (the opposite frame edge),
@@ -52,6 +67,10 @@ def _filter2d_sharded_impl(frame: jax.Array, coeffs: jax.Array, mesh: Mesh,
     ``requant`` applies the same fused epilogue contract as ``filter2d``
     per shard, so the ring's *output* tiles (and the gathered result) are
     storage-width too.
+
+    ``on_halo_bytes``, when given, is called while tracing with the bytes
+    the two ``ppermute`` operands carry per call, summed over the shards
+    (0 on a one-shard mesh).
     """
     spec = border if border is not None else BorderSpec(border_policy)
     if spec.policy == "neglect":
@@ -77,8 +96,9 @@ def _filter2d_sharded_impl(frame: jax.Array, coeffs: jax.Array, mesh: Mesh,
     w = coeffs.shape[-1]
     r = (w - 1) // 2
     n_shards = mesh.shape[axis]
-    assert H % n_shards == 0 and H // n_shards >= r, (H, n_shards, r)
     if n_shards == 1:
+        if on_halo_bytes is not None:
+            on_halo_bytes(0)
         from repro.core.filter2d import _filter2d_impl
         qc = jnp.asarray(quantize_constant(spec.constant, frame.dtype))
         y = _filter2d_impl(frame, coeffs, form=form,
@@ -97,21 +117,28 @@ def _filter2d_sharded_impl(frame: jax.Array, coeffs: jax.Array, mesh: Mesh,
         # up-neighbour-ward, bottom r down — 2·r·W·C·storage bytes of wire
         fwd = [(i, (i + 1) % n_shards) for i in range(n_shards)]
         bwd = [(i, (i - 1) % n_shards) for i in range(n_shards)]
-        top_from_above = jax.lax.ppermute(xs[:, Hs - r:], axis, fwd)
-        bot_from_below = jax.lax.ppermute(xs[:, :r], axis, bwd)
-        ext = jnp.concatenate([top_from_above, xs, bot_from_below], axis=1)
-        if spec.policy != "wrap":
-            # true frame edges: remap locally (halo rows from the
-            # wrap-neighbour are garbage there and are overwritten by the
-            # remap). Under wrap the ring delivery IS the right answer.
-            first_src = jnp.concatenate([xs, bot_from_below], axis=1)
-            hi_first = gather_rows(first_src, jnp.arange(-r, Hs + r), spec,
-                                   axis=1)
-            ext = jnp.where(idx == 0, hi_first, ext)
-            last_src = jnp.concatenate([top_from_above, xs], axis=1)
-            hi_last = gather_rows(last_src, jnp.arange(0, Hs + 2 * r), spec,
+        with jax.named_scope(HALO_SCOPE):
+            up, down = xs[:, Hs - r:], xs[:, :r]
+            if on_halo_bytes is not None:
+                on_halo_bytes(n_shards * (up.size * up.dtype.itemsize
+                                          + down.size * down.dtype.itemsize))
+            top_from_above = jax.lax.ppermute(up, axis, fwd)
+            bot_from_below = jax.lax.ppermute(down, axis, bwd)
+            ext = jnp.concatenate([top_from_above, xs, bot_from_below],
                                   axis=1)
-            ext = jnp.where(idx == n_shards - 1, hi_last, ext)
+            if spec.policy != "wrap":
+                # true frame edges: remap locally (halo rows from the
+                # wrap-neighbour are garbage there and are overwritten by
+                # the remap). Under wrap the ring delivery IS the right
+                # answer.
+                first_src = jnp.concatenate([xs, bot_from_below], axis=1)
+                hi_first = gather_rows(first_src, jnp.arange(-r, Hs + r),
+                                       spec, axis=1)
+                ext = jnp.where(idx == 0, hi_first, ext)
+                last_src = jnp.concatenate([top_from_above, xs], axis=1)
+                hi_last = gather_rows(last_src, jnp.arange(0, Hs + 2 * r),
+                                      spec, axis=1)
+                ext = jnp.where(idx == n_shards - 1, hi_last, ext)
         # column halo: plain index remap, local
         wi = jnp.arange(-r, W + r)
         ext = gather_rows(ext, wi, spec, axis=2)

@@ -329,6 +329,12 @@ class CompiledFilter:
             if spec.separable:
                 raise ValueError(f"execution={execution!r} has no "
                                  "separable path; use 'core' or 'pallas'")
+        # bytes the halo exchange moves per call, summed over the shards;
+        # the sharded executor sets it when its program is first traced
+        self.halo_bytes = None
+        if execution == "sharded":
+            from repro.core.distributed import check_row_split
+            check_row_split(self._H, mesh.shape[axis], r)
 
         self.regime = None
         self.strip_h = None
@@ -528,13 +534,17 @@ class CompiledFilter:
             mesh, ax = self.mesh, self.axis
             rq_static = rq.gain_free() if rq is not None else None
 
+            def keep_halo_bytes(nbytes):
+                self.halo_bytes = int(nbytes)
+
             def impl(frame, co, q=None):
                 # gains ride into the shard_map as a replicated traced
                 # operand: each shard requantises its own tile, so the
                 # gathered tiles stay storage-width
-                return _filter2d_sharded_impl(frame, co, mesh, q, axis=ax,
-                                              form=spec.form, border=border,
-                                              requant=rq_static)
+                return _filter2d_sharded_impl(
+                    frame, co, mesh, q, axis=ax, form=spec.form,
+                    border=border, requant=rq_static,
+                    on_halo_bytes=keep_halo_bytes)
             return impl
 
         assert self.execution == "pallas", self.execution
@@ -646,11 +656,19 @@ class CompiledFilter:
         # the jitted executable, with no span opened
         if self.profile_dump is None and not obs_profiler.recording():
             return self._fn(*self._operands(frame, coeffs, gains))
+        reg = obs_metrics.REGISTRY
         with obs_profiler.span("repro.call.operands"):
-            args = self._operands(frame, coeffs, gains, obs_metrics.REGISTRY)
+            args = self._operands(frame, coeffs, gains, reg)
         if obs_events._TRACE is None and self.profile_dump is None:
-            return self._launch(args)
-        return self._instrumented_call(args)
+            y = self._launch(args)
+        else:
+            y = self._instrumented_call(args)
+        if self.execution == "sharded":
+            # after the launch: the first call traces the program, which
+            # is when the exchange's bytes become known
+            reg.counter("pipeline.sharded_calls").inc()
+            reg.counter("pipeline.halo_bytes").inc(self.halo_bytes)
+        return y
 
     def _operands(self, frame, coeffs, gains, reg=None):
         """The executable's operands: the frame checked against the
