@@ -4,10 +4,11 @@ The paper maps a general `w×w` runtime-coefficient filter onto DSP48E1
 blocks in two *forms* and three *adder-tree layouts*. On TPU the analogous
 design space is *how the w² multiply-reduce is scheduled onto the MXU/VPU*:
 
-  ``direct``      im2row patch matrix [P, w²] × coeff vector/matrix on the
-                  MXU. The MXU's internal systolic reduction tree plays the
-                  role of the paper's **DSP layout** adder tree (adds in
-                  silicon, highest throughput).
+  ``direct``      the w² shifted-frame×scalar products summed on the VPU
+                  in elementwise fusions of at most 32 taps each (no
+                  patch tensor) — the paper's **DSP layout**, whose
+                  cascade adds in silicon; in the Pallas kernel, one
+                  fused sum.
   ``transposed``  shift-and-accumulate: w² shifted frame×scalar MACs on the
                   VPU, running accumulator — the paper's transposed form
                   (MAC chains, no tree, no patch materialisation).
@@ -180,17 +181,29 @@ def _taps(xp: jax.Array, coeffs: jax.Array, H: int, W: int):
 # ---------------------------------------------------------------------------
 
 
+# A TPU loop fusion takes about 32 scalar operands. Past that, XLA moves
+# the extra coefficients out of the fusion as broadcasts to full planes,
+# each written to and read back from memory. So the direct form sums its
+# taps in passes of at most this many, each pass one fusion.
+_TAPS_PER_PASS = 32
+
+
 def _direct(xp: jax.Array, coeffs: jax.Array, H: int, W: int) -> jax.Array:
-    """im2row → matmul. The patch matrix is built per output pixel row-window
-    and contracted on the MXU; its internal reduction tree does the adds."""
-    w = coeffs.shape[-1]
-    B, _, _, C = xp.shape
-    # Gather w² shifted planes then contract: [B,H,W,C,w²] @ [w²]
-    planes = jnp.stack(
-        [_shifted(xp, i, j, H, W) for i in range(w) for j in range(w)],
-        axis=-1)  # [B,H,W,C,w2]
-    return jnp.einsum("bhwck,k->bhwc", planes,
-                      coeffs.reshape(-1).astype(xp.dtype))
+    """The w² taps summed in raster order in the accumulator dtype,
+    ``acc = Σ c[i,j]·xp[:, i:i+H, j:j+W, :]``, in as few balanced passes
+    of at most ``_TAPS_PER_PASS`` taps as there can be. Each pass is one
+    elementwise fusion that reads the extended frame and the running sum
+    and holds its coefficients as scalars; an optimization barrier keeps
+    XLA from merging the passes. No value carries a trailing w² axis."""
+    terms = _taps(xp, coeffs.astype(xp.dtype), H, W)
+    passes = math.ceil(len(terms) / _TAPS_PER_PASS)
+    per_pass = math.ceil(len(terms) / passes)
+    acc = None
+    for k, (plane, c) in enumerate(terms):
+        if k and k % per_pass == 0:
+            acc = jax.lax.optimization_barrier(acc)
+        acc = plane * c if acc is None else acc + plane * c
+    return acc
 
 
 def _transposed(xp: jax.Array, coeffs: jax.Array, H: int, W: int) -> jax.Array:
