@@ -16,7 +16,7 @@ from repro.core import filters
 from repro.core.borders import BorderSpec
 from repro.core.filter2d import (FORMS, filter2d, macs_per_pixel,
                                  reduction_depth, startup_latency_rows)
-from repro.core.streaming import filter2d_streaming
+from repro.core.pipeline import Filter2D
 
 H, W = 480, 640          # the paper's synthesis target frame
 
@@ -111,7 +111,7 @@ def streaming_vs_resident():
     k = jnp.asarray(filters.gaussian(7))
     us_res = time_call(lambda a, b: filter2d(a, b), x, k)
     us_str = time_call(
-        lambda a, b: filter2d_streaming(a, b, strip_h=96), x, k)
+        Filter2D(window=7).compile(x, "streaming", strip_h=96), x, k)
     return [row("stream/resident", us_res, ""),
             row("stream/rowbuffer96", us_str,
                 f"ratio={us_str / max(us_res, 1e-9):.2f}")]

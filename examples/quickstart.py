@@ -8,8 +8,8 @@ policies, the streaming row-buffer executor, and the Pallas kernel path.
 import numpy as np
 import jax.numpy as jnp
 
-from repro.core import (BorderSpec, FORMS, default_bank, filter2d, 
-                        filter2d_streaming, preset)
+from repro.core import (BorderSpec, FORMS, Filter2D, default_bank, filter2d,
+                        preset)
 from repro.data import SyntheticFrames
 from repro.kernels.filter2d import filter2d_pallas
 
@@ -43,7 +43,8 @@ def main():
 
     # 4. streaming row-buffer executor == frame-resident result
     y_res = filter2d(frame, k)
-    y_str = filter2d_streaming(frame, k, strip_h=96)
+    y_str = Filter2D(window=7).compile(frame, "streaming", strip_h=96)(
+        frame, k)
     print(f"streaming vs resident: max |Δ| = "
           f"{float(jnp.max(jnp.abs(y_str - y_res))):.2e}")
 

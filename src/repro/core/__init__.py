@@ -6,12 +6,15 @@ Submodules:
   filters      — runtime coefficient file + preset bank (paper §I/§II)
   filter2d     — direct/transposed/tree/compress forms (paper §II)
   requant      — the fused output-scaler spec + numpy reference (paper §IV)
-  streaming    — row-strip streaming executor with carried row buffer
+  streaming    — row-strip streaming executor with carried row buffer,
+                 and the band body both row-buffer executors run
   distributed  — shard_map halo exchange (the row buffer, distributed)
   pipeline     — the plan-and-execute front door: Filter2D → CompiledFilter
 
 ``Filter2D(...).compile(frame_spec)`` is the one front door over every
-executor; the per-executor entry points remain as thin wrappers. The
+executor: ``compile(frame_spec, "streaming", strip_h=...)`` runs the strip
+scan and ``compile(frame_spec, "sharded", mesh=...)`` the row-sharded
+executor, which have no entry point of their own. The
 export list below is pinned by a snapshot test (tests/test_public_api.py)
 so the public surface cannot fork silently.
 """
@@ -23,8 +26,7 @@ from repro.core.filter2d import (FORMS, filter2d, filter2d_xla, filter_bank,
 from repro.core.filters import (CoefficientFile, decompose_separable,
                                 default_bank, preset)
 from repro.core.requant import RequantSpec, requantize_ref
-from repro.core.streaming import filter2d_streaming, strip_height_for_vmem
-from repro.core.distributed import filter2d_sharded
+from repro.core.streaming import strip_height_for_vmem
 from repro.core.pipeline import (DEFAULT_VMEM_BUDGET, EXECUTIONS,
                                  CompiledFilter, Filter2D)
 
@@ -43,8 +45,6 @@ __all__ = [
     "decompose_separable",
     "default_bank",
     "filter2d",
-    "filter2d_sharded",
-    "filter2d_streaming",
     "filter2d_xla",
     "filter_bank",
     "macs_per_pixel",

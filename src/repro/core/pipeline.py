@@ -23,10 +23,12 @@ wordlengths the same way. This module is that split for the TPU port:
     the frame geometry or the executor compiles fresh by construction
     (each compiled pipeline owns its cache).
 
-The seven historical entry points (``filter2d``, ``filter_bank``,
-``filter2d_xla``, ``filter2d_streaming``, ``filter2d_sharded``,
-``filter2d_pallas``, ``filter_bank_pallas``) are thin wrappers over this
-path; ``compile`` results are memoised so the wrappers stay cheap.
+The five historical entry points (``filter2d``, ``filter_bank``,
+``filter2d_xla``, ``filter2d_pallas``, ``filter_bank_pallas``) are thin
+wrappers over this path; ``compile`` results are memoised so the wrappers
+stay cheap. The strip scan and the row-sharded executor have no entry
+point of their own: ``compile(..., 'streaming')`` and ``compile(...,
+'sharded', mesh=...)`` reach them.
 """
 from __future__ import annotations
 
@@ -44,6 +46,7 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.border_spec import BorderSpec, quantize_constant
+from repro.core.distributed import _filter2d_sharded_impl, check_row_split
 from repro.core.filter2d import (FORMS, _filter2d_impl, _filter2d_sep_impl,
                                  _filter2d_xla_impl, _filter_bank_impl,
                                  apply_requant, apply_requant_params,
@@ -329,11 +332,13 @@ class CompiledFilter:
             if spec.separable:
                 raise ValueError(f"execution={execution!r} has no "
                                  "separable path; use 'core' or 'pallas'")
+        if execution in ("streaming", "sharded") and not same:
+            raise ValueError(f"execution={execution!r} keeps the frame "
+                             "size; 'neglect' takes 'core' or 'pallas'")
         # bytes the halo exchange moves per call, summed over the shards;
         # the sharded executor sets it when its program is first traced
         self.halo_bytes = None
         if execution == "sharded":
-            from repro.core.distributed import check_row_split
             check_row_split(self._H, mesh.shape[axis], r)
 
         self.regime = None
@@ -486,8 +491,13 @@ class CompiledFilter:
                                      out_dtype=rq.np_dtype)
             return apply_requant_params(y, q, rq)
 
+        # constant(c) as the storage dtype holds it (the shared rule), for
+        # the core oracle and the two row-buffer executors
+        qc = quantize_constant(border.constant, dt)
+        band = dataclasses.replace(border, constant=qc)
+        rq_static = rq.gain_free() if rq is not None else None
+
         if self.execution == "core":
-            qc = quantize_constant(border.constant, dt)
             if spec.separable:
                 def impl(frame, co, q=None):
                     y = _filter2d_sep_impl(
@@ -516,23 +526,18 @@ class CompiledFilter:
 
         if self.execution == "streaming":
             strip_h = self.strip_h
-            rq_static = rq.gain_free() if rq is not None else None
 
             def impl(frame, co, q=None):
                 # the scan requantises each emitted strip itself (traced
                 # gains operand): the output stream leaves at storage
                 # width strip by strip, not via a post-scan pass
-                return _filter2d_streaming_impl(frame, co, q,
-                                                form=spec.form,
-                                                border=border,
-                                                strip_h=strip_h,
-                                                requant=rq_static)
+                return _filter2d_streaming_impl(
+                    frame, co, q, border=band, form=spec.form,
+                    strip_h=strip_h, requant=rq_static)
             return impl
 
         if self.execution == "sharded":
-            from repro.core.distributed import _filter2d_sharded_impl
             mesh, ax = self.mesh, self.axis
-            rq_static = rq.gain_free() if rq is not None else None
 
             def keep_halo_bytes(nbytes):
                 self.halo_bytes = int(nbytes)
@@ -542,13 +547,12 @@ class CompiledFilter:
                 # operand: each shard requantises its own tile, so the
                 # gathered tiles stay storage-width
                 return _filter2d_sharded_impl(
-                    frame, co, mesh, q, axis=ax, form=spec.form,
-                    border=border, requant=rq_static,
+                    frame, co, q, mesh=mesh, axis=ax, border=band,
+                    form=spec.form, requant=rq_static,
                     on_halo_bytes=keep_halo_bytes)
             return impl
 
         assert self.execution == "pallas", self.execution
-        rq_static = rq.gain_free() if rq is not None else None
         form = "separable" if spec.separable else spec.form
         n = spec.num_filters
         regime, S, Tw = self.regime, self.strip_h, self.tile_w

@@ -1,20 +1,31 @@
-"""Row-strip streaming executor — the paper's dataflow at the XLA level.
+"""Row-strip streaming executor — the paper's dataflow at the XLA level —
+and the band body it shares with the row-sharded executor.
 
 The FPGA design streams one pixel per clock through a (w−1)-row buffer so a
 full frame never needs to be resident. The TPU translation processes one
 *row strip* per step: a `jax.lax.scan` over strips where the carry is the
-last (w−1) rows of the previous strip — exactly the paper's row buffer. The
-strip height is chosen so (strip + halo) fits a fixed VMEM budget, which is
-what bounds on-chip memory exactly as the row buffer bounds BRAM.
+last r = (w−1)/2 rows of the previous strip — exactly the paper's row
+buffer. The strip height is chosen so (strip + halo) fits a fixed VMEM
+budget, which is what bounds on-chip memory exactly as the row buffer
+bounds BRAM.
 
-Border rows are sourced from the carry (top) / in-strip lookahead (bottom)
-with the border policy's index remap applied only at the first/last strip —
-the overlapped priming & flushing idea: no stall, no extra pass, the stream
-of strips never stops. ``wrap`` needs the *opposite* frame edge, which a
-row buffer by construction no longer holds — it is served by a **prologue**:
-the r bottom rows are captured before the scan starts and spliced in at the
-first strip (and symmetrically the top rows at the last strip), the same
-scheme the Pallas halo engine implements with prologue DMAs.
+A *band* is a run of rows plus r rows of halo on each side: a strip of the
+scan, or a shard of ``core.distributed``. :func:`band_rows` assembles one
+and remaps its halo only at a true frame edge — the overlapped priming &
+flushing idea: no stall, no extra pass, the stream of bands never stops.
+:func:`filter_band` then widens the column-extended band to the
+accumulator, runs the form and the requantising epilogue, so each band
+leaves at storage width. The scan widens fixed-point frames and extends
+their columns once, over the whole frame, before it starts: done per
+strip, the column remap is a pass of its own over every band, which on a
+TPU v5e cost more than the frame-wide pass it replaces.
+
+``wrap`` needs the *opposite* frame edge, which a row buffer by
+construction no longer holds — it is served by a **prologue**: the carry
+starts as the frame's last r rows, and the lookahead closes a ring, so the
+last strip's rows below are the frame's first r rows (the same ring the
+shards' halo exchange runs on, and the scheme the Pallas halo engine
+implements with prologue DMAs).
 
 This file is the *jnp* streaming path; the Pallas kernel in
 ``kernels/filter2d`` implements the same schedule with an explicit VMEM
@@ -22,18 +33,15 @@ scratch and in-kernel halo DMA (``kernels/filter2d/halo``).
 """
 from __future__ import annotations
 
-import dataclasses
-import functools
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
-from repro.core.border_spec import quantize_constant
-from repro.core.borders import BorderSpec, gather_rows
-from repro.core.filter2d import (_FORM_FNS, _as_nhwc, _filter2d_impl, 
-                                 _un_nhwc, apply_requant_params, 
-                                 is_fixed_point, resolve_requant)
+from repro.core.border_spec import BorderSpec
+from repro.core.borders import gather_rows
+from repro.core.filter2d import (_FORM_FNS, _as_nhwc, _un_nhwc,
+                                 apply_requant_params, is_fixed_point)
 from repro.core.requant import RequantSpec
 
 
@@ -48,128 +56,91 @@ def strip_height_for_vmem(width: int, channels: int, w: int,
     return max(8, int(h))
 
 
-@functools.partial(
-    jax.jit, static_argnames=("form", "border_policy", "strip_h", "border",
-                              "requant"))
+def band_rows(above: jax.Array, body: jax.Array, below: jax.Array,
+              i: jax.Array, n_bands: int, border: BorderSpec) -> jax.Array:
+    """The rows ``[above | body | below]`` of band ``i`` of ``n_bands``
+    ([B, S + 2r, ...]), with the halo past a true frame edge remapped
+    under ``border``.
+
+    ``above`` and ``below`` are the r rows next to ``body`` on the ring of
+    bands: for the first band ``above`` is the frame's last r rows, for
+    the last band ``below`` its first r rows, which is what ``wrap``
+    reads. Every other policy remaps the first band's top halo and the
+    last band's bottom halo from the rows inside the frame. A single band
+    (decided from the static ``n_bands``) has both edges remapped.
+    """
+    r, S = above.shape[1], body.shape[1]
+    if border.policy != "wrap" and n_bands == 1:
+        return gather_rows(body, jnp.arange(-r, S + r), border, axis=1)
+    ext = jnp.concatenate([above, body, below], axis=1)
+    if border.policy == "wrap":
+        return ext
+    first = gather_rows(jnp.concatenate([body, below], axis=1),
+                        jnp.arange(-r, S + r), border, axis=1)
+    ext = jnp.where(i == 0, first, ext)
+    last = gather_rows(jnp.concatenate([above, body], axis=1),
+                       jnp.arange(0, S + 2 * r), border, axis=1)
+    return jnp.where(i == n_bands - 1, last, ext)
+
+
+def filter_band(ext: jax.Array, coeffs: jax.Array,
+                q: Optional[jax.Array], *, form: str,
+                requant: Optional[RequantSpec]) -> jax.Array:
+    """Filter the band ``ext`` ([B, S + 2r, W + 2r, C]: the rows of
+    :func:`band_rows` with their columns extended) to its S output rows.
+
+    Fixed-point rows widen to the int32 accumulator here, at the MAC, and
+    ``requant`` (the static half; ``q`` the traced [1, 2] gains) brings
+    the band back to storage width.
+    """
+    r = (coeffs.shape[-1] - 1) // 2
+    if is_fixed_point(ext.dtype):
+        ext = ext.astype(jnp.int32)
+    y = _FORM_FNS[form](ext, coeffs, ext.shape[1] - 2 * r,
+                        ext.shape[2] - 2 * r)
+    return y if requant is None else apply_requant_params(y, q, requant)
+
+
 def _filter2d_streaming_impl(frame: jax.Array, coeffs: jax.Array,
-                             q_params: Optional[jax.Array] = None, *,
-                             form: str = "direct",
-                             border_policy: str = "mirror",
-                             strip_h: int = 64,
-                             border: Optional[BorderSpec] = None,
-                             requant: Optional[RequantSpec] = None
-                             ) -> jax.Array:
-    """The strip-scan executable behind :func:`filter2d_streaming` (and
-    the pipeline's ``execution='streaming'``). ``requant`` is the static
-    half of the epilogue (rounding mode + storage dtype shape the trace);
-    the (multiplier, shift) gains ride as the *traced* ``q_params``
-    ``[1, 2]`` operand — defaulting to the spec's own — so the pipeline
-    swaps gains without recompiling while each emitted strip still leaves
-    the scan at storage width (the PR-4 write-side contract)."""
-    spec = border if border is not None else BorderSpec(border_policy)
-    if spec.policy == "neglect":
-        raise ValueError("streaming path does not support 'neglect'")
-    rq = resolve_requant(frame.dtype, requant)
-    if rq is not None and q_params is None:
-        q_params = jnp.asarray(rq.params(1), jnp.int32)
+                             q: Optional[jax.Array], *, border: BorderSpec,
+                             form: str, strip_h: int,
+                             requant: Optional[RequantSpec]) -> jax.Array:
+    """The strip scan behind ``Filter2D.compile(..., 'streaming')``.
 
-    def epilogue(y):
-        return y if rq is None else apply_requant_params(y, q_params, rq)
-
-    # fixed-point: quantize constant(c) against the *storage* dtype first
-    # (the shared rule), then run the stream in the int32 accumulator
-    # dtype — bit-exact with core.filter2d and the Pallas kernels.
-    src_frame, src_coeffs = frame, coeffs   # pre-widening, for delegation
-    if is_fixed_point(frame.dtype):
-        spec = dataclasses.replace(
-            spec, constant=quantize_constant(spec.constant, frame.dtype))
-        frame = frame.astype(jnp.int32)
-        coeffs = coeffs.astype(jnp.int32)
+    ``border`` is a same-size policy with its constant already quantized
+    against the storage dtype; ``requant`` is the static half of the
+    epilogue and ``q`` the traced gains (``None`` without requant), so
+    gains swap without recompiling. Frame height must divide by
+    ``strip_h`` and ``strip_h >= w-1`` (the carry must fit inside one
+    strip).
+    """
     x, add_b, add_c = _as_nhwc(frame)
     B, H, W, C = x.shape
     w = coeffs.shape[-1]
     r = (w - 1) // 2
     assert H % strip_h == 0 and strip_h >= w - 1, (H, strip_h, w)
     n_strips = H // strip_h
-    if n_strips < 2:  # degenerate launch: whole frame is one strip
-        qc = jnp.asarray(quantize_constant(spec.constant, src_frame.dtype))
-        return epilogue(_filter2d_impl(src_frame, src_coeffs, form=form,
-                                       border_policy=spec.policy,
-                                       border_constant=qc))
-
-    # Pre-extend columns once (width axis) — the column mux of the window
-    # cache. This is index remap, not a padded HBM pass, under jit.
-    wi = jnp.arange(-r, W + r)
-    xc = gather_rows(x, wi, spec, axis=2)  # [B, H, W+2r, C]
-
+    if is_fixed_point(x.dtype):
+        x = x.astype(jnp.int32)
+    xc = gather_rows(x, jnp.arange(-r, W + r), border, axis=2)
     strips = xc.reshape(B, n_strips, strip_h, W + 2 * r, C).swapaxes(0, 1)
-    # wrap prologue: the opposite-edge rows the row buffer cannot hold
-    top_rows = xc[:, :r] if r else xc[:, :0]
-    bot_rows = xc[:, H - r:] if r else xc[:, :0]
+    if border.policy == "wrap":
+        # the prologue: the ring of strips closes on the opposite edge
+        above0, below_last = strips[-1][:, strip_h - r:], strips[:1]
+    else:
+        # band_rows remaps the rows past either frame edge: none is read
+        above0 = jnp.zeros((B, r, W + 2 * r, C), xc.dtype)
+        below_last = strips[-1:]
+    nxt_strips = jnp.concatenate([strips[1:], below_last], axis=0)
 
     def step(carry, inputs):
-        row_buf, i = carry                  # [B, r, W+2r, C] rows above
-        strip, nxt = inputs                 # current strip, lookahead strip
-        # Interior: ext rows = [carry | strip | next strip's first r rows]
-        ext = jnp.concatenate([row_buf, strip, nxt[:, :r]], axis=1)
-        if spec.policy == "wrap":
-            # first/last strip: splice the prologue's opposite-edge rows
-            hi_first = jnp.concatenate([bot_rows, strip, nxt[:, :r]], axis=1)
-            hi_last = jnp.concatenate([row_buf, strip, top_rows], axis=1)
-        else:
-            # First strip: top halo = border remap into [strip | lookahead]
-            first_src = jnp.concatenate([strip, nxt[:, :r]], axis=1)
-            hi_first = gather_rows(first_src, jnp.arange(-r, strip_h + r),
-                                   spec, axis=1)
-            # Last strip: bottom halo = border remap into [carry | strip]
-            last_src = jnp.concatenate([row_buf, strip], axis=1)
-            hi_last = gather_rows(last_src, jnp.arange(0, strip_h + 2 * r),
-                                  spec, axis=1)
-        ext = jnp.where(i == 0, hi_first, ext)
-        ext = jnp.where(i == n_strips - 1, hi_last, ext)
-        # fused epilogue per emitted strip: the output stream leaves at
-        # storage width, exactly like the Pallas kernel's store (the
-        # traced gains are a scan constant)
-        y = epilogue(_FORM_FNS[form](ext, coeffs, strip_h, W))
-        new_buf = strip[:, strip_h - r:] if r else row_buf
-        return (new_buf, i + 1), y
+        above, i = carry
+        strip, nxt = inputs
+        ext = band_rows(above, strip, nxt[:, :r], i, n_strips, border)
+        y = filter_band(ext, coeffs, q, form=form, requant=requant)
+        return (strip[:, strip_h - r:], i + 1), y
 
-    nxt_strips = jnp.concatenate([strips[1:], strips[-1:]], axis=0)
-    init = (jnp.zeros((B, r, W + 2 * r, C), x.dtype),
-            jnp.asarray(0, jnp.int32))
-    _, ys = jax.lax.scan(step, init, (strips, nxt_strips))
+    _, ys = jax.lax.scan(step, (above0, jnp.asarray(0, jnp.int32)),
+                         (strips, nxt_strips))
     y = ys.swapaxes(0, 1).reshape(B, H, W, C)
     return _un_nhwc(y, add_b, add_c)
-
-
-def filter2d_streaming(frame: jax.Array, coeffs: jax.Array, *,
-                       form: str = "direct", border_policy: str = "mirror",
-                       strip_h: int = 64,
-                       border: Optional[BorderSpec] = None,
-                       requant: Optional[RequantSpec] = None) -> jax.Array:
-    """Filter a frame strip-by-strip with a carried (w−1)-row buffer.
-
-    Semantics identical to ``filter2d(...)`` for every same-size policy
-    (``zero``/``constant(c)``, ``replicate``/``duplicate``, ``reflect``/
-    ``mirror``, ``mirror_dup``, ``wrap``). Pass a full ``BorderSpec`` via
-    ``border`` (wins over ``border_policy``) for non-zero constants. Frame
-    height must divide by ``strip_h`` and ``strip_h >= w-1`` (the carry
-    must fit inside one strip). ``requant`` applies the same fused
-    epilogue contract as ``filter2d``: each emitted strip is scaled,
-    rounded and saturated to the spec's storage dtype, so the stream of
-    output strips is storage-width like the input stream.
-
-    Thin wrapper over ``core.pipeline.Filter2D``
-    (``execution='streaming'``) — prefer the compiled front door for
-    served pipelines; it can also derive ``strip_h`` from a VMEM budget
-    instead of taking it as a knob.
-    """
-    from repro.core.pipeline import Filter2D
-    spec_b = border if border is not None else BorderSpec(border_policy)
-    rq = resolve_requant(frame.dtype, requant)
-    spec = Filter2D(window=int(jnp.shape(coeffs)[-1]), form=form,
-                    border=spec_b,
-                    dtype=jnp.dtype(frame.dtype).name,
-                    requant=rq.gain_free() if rq is not None else None)
-    cf = spec.compile(frame, "streaming", strip_h=strip_h)
-    return cf(frame, coeffs, gains=rq)

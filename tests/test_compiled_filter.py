@@ -89,19 +89,23 @@ def test_executor_parity(execution, form, policy, dtype, rng):
                                    rtol=3e-4, atol=3e-4)
 
 
-@pytest.mark.parametrize("execution", EXECUTORS)
-def test_executor_parity_requant(execution, rng):
+@pytest.mark.parametrize("execution,dtype", [
+    *(pytest.param(e, "int8", id=e) for e in EXECUTORS),
+    # the band body of both row buffers: four strips, one shard
+    *(pytest.param(e, "uint8", id=f"uint8-{e}")
+      for e in ("streaming", "sharded"))])
+def test_executor_parity_requant(execution, dtype, rng):
     """The requantising epilogue lands bit-identically on every executor
     (the pipeline applies it with traced gains; the oracle with static
     ones) — pinned against the numpy reference, not just the oracle."""
-    x = _frame(rng, np.int8)
-    k = _kernel(rng, np.int8)
-    rq = RequantSpec.unity_gain(k, "int8")
+    x = _frame(rng, dtype)
+    k = _kernel(rng, dtype)
+    rq = RequantSpec.unity_gain(k, dtype)
     ref = filter2d(jnp.asarray(x), jnp.asarray(k), requant=rq)
-    spec = Filter2D(window=5, dtype="int8", requant=rq.gain_free())
+    spec = Filter2D(window=5, dtype=dtype, requant=rq.gain_free())
     cf = _compile(spec, jnp.asarray(x), execution)
     got = cf(jnp.asarray(x), jnp.asarray(k), gains=rq)
-    assert got.dtype == jnp.int8
+    assert got.dtype == jnp.dtype(dtype)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
     # and the epilogue itself against the int64 numpy reference
     acc = filter2d(jnp.asarray(x), jnp.asarray(k))

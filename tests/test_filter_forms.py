@@ -10,7 +10,7 @@ from repro.core.borders import BorderSpec, np_pad_mode
 from repro.core.filter2d import (FORMS, filter2d, filter2d_xla, filter_bank,
                                  macs_per_pixel, reduction_depth,
                                  startup_latency_rows)
-from repro.core.streaming import filter2d_streaming
+from repro.core.pipeline import Filter2D
 
 
 def np_filter(x, k, mode):
@@ -79,7 +79,7 @@ def test_filter_bank(rng):
                                atol=2e-5)  # identity slot
 
 
-@pytest.mark.parametrize("sh", [8, 16, 32])
+@pytest.mark.parametrize("sh", [8, 16, 32, 64])   # 64: one strip
 @pytest.mark.parametrize("w", [3, 5, 7])
 @pytest.mark.parametrize("policy", ["mirror", "mirror_dup", "duplicate",
                                     "constant", "wrap"])
@@ -90,8 +90,8 @@ def test_streaming_equals_resident(sh, w, policy):
     x = rng.standard_normal((64, 24)).astype(np.float32)
     k = jnp.asarray(filters.gaussian(w))
     ref = filter2d(jnp.asarray(x), k, border=BorderSpec(policy))
-    got = filter2d_streaming(jnp.asarray(x), k, border_policy=policy,
-                             strip_h=sh)
+    spec = Filter2D(window=w, border=BorderSpec(policy))
+    got = spec.compile(x.shape, "streaming", strip_h=sh)(jnp.asarray(x), k)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref), rtol=3e-5,
                                atol=3e-5)
 
@@ -104,7 +104,8 @@ def test_streaming_nonzero_constant():
     k = jnp.asarray(filters.gaussian(5))
     spec = BorderSpec("constant", 4.5)
     ref = filter2d(jnp.asarray(x), k, border=spec)
-    got = filter2d_streaming(jnp.asarray(x), k, border=spec, strip_h=16)
+    got = Filter2D(window=5, border=spec).compile(
+        x.shape, "streaming", strip_h=16)(jnp.asarray(x), k)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref), rtol=3e-5,
                                atol=3e-5)
 

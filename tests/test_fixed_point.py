@@ -13,7 +13,7 @@ import pytest
 from repro.core.border_spec import (BorderSpec, SAME_SIZE_POLICIES,
                                     np_pad_mode, quantize_constant)
 from repro.core.filter2d import filter2d, filter_bank
-from repro.core.streaming import filter2d_streaming
+from repro.core.pipeline import Filter2D
 from repro.kernels.filter2d import (filter2d_pallas, filter_bank_pallas,
                                     make_plan, read_bytes_per_pixel)
 
@@ -161,8 +161,8 @@ def test_out_of_range_constant_same_everywhere(dtype, c, rng):
     pallas = filter2d_pallas(jnp.asarray(x), jnp.asarray(k), border=spec,
                              regime="stream", strip_h=8, tile_w=128)
     np.testing.assert_array_equal(np.asarray(pallas), want)
-    stream = filter2d_streaming(jnp.asarray(x), jnp.asarray(k), strip_h=8,
-                                border=spec)
+    stream = Filter2D(window=3, border=spec, dtype=x.dtype.name).compile(
+        x.shape, "streaming", strip_h=8)(jnp.asarray(x), jnp.asarray(k))
     np.testing.assert_array_equal(np.asarray(stream), want)
 
 
@@ -207,13 +207,19 @@ def test_separable_guards_for_integer_frames(rng):
 # -- streaming executor parity ----------------------------------------------
 
 
-@pytest.mark.parametrize("policy", FIVE_POLICIES)
-def test_streaming_executor_int_parity(policy, rng):
+# strip 8: four strips; 16: every strip is the first or the last (wrap's
+# prologue rows on both); 32: one strip, both edges remapped in it
+@pytest.mark.parametrize("policy,strip_h", [
+    *(pytest.param(p, 8, id=p) for p in FIVE_POLICIES),
+    *(pytest.param(p, s, id=f"{p}-strip{s}") for s in (16, 32)
+      for p in FIVE_POLICIES)])
+def test_streaming_executor_int_parity(policy, strip_h, rng):
     x = _frame(rng, np.int8, (32, 40))
     k = rng.integers(-4, 5, (3, 3)).astype(np.int32)
     spec = BorderSpec(policy, 2.0)
-    got = filter2d_streaming(jnp.asarray(x), jnp.asarray(k), strip_h=8,
-                             border=spec)
+    got = Filter2D(window=3, border=spec, dtype="int8").compile(
+        x.shape, "streaming", strip_h=strip_h)(jnp.asarray(x),
+                                               jnp.asarray(k))
     assert got.dtype == jnp.int32
     np.testing.assert_array_equal(np.asarray(got),
                                   np_filter_int32(x, k, policy, constant=2.0))

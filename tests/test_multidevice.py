@@ -32,7 +32,7 @@ def test_sharded_filter_halo_exchange():
     _run("""
     import numpy as np, jax, jax.numpy as jnp
     from repro.core.filter2d import filter2d
-    from repro.core.distributed import filter2d_sharded
+    from repro.core.pipeline import Filter2D
     from repro.core.borders import BorderSpec
     from repro.core import filters
     mesh = jax.make_mesh((4,), ("data",))
@@ -41,7 +41,9 @@ def test_sharded_filter_halo_exchange():
     for pol in ("mirror", "duplicate", "constant"):
         k = jnp.asarray(filters.gaussian(5))
         ref = filter2d(jnp.asarray(x), k, border=BorderSpec(pol))
-        y = filter2d_sharded(jnp.asarray(x), k, mesh, border_policy=pol)
+        cf = Filter2D(window=5, border=BorderSpec(pol)).compile(
+            x.shape, "sharded", mesh=mesh)
+        y = cf(jnp.asarray(x), k)
         np.testing.assert_allclose(np.asarray(y), np.asarray(ref),
                                    rtol=2e-5, atol=2e-5)
     print("OK")
@@ -55,7 +57,7 @@ def test_sharded_fixed_point_narrow_ring_and_requant():
     _run("""
     import numpy as np, jax, jax.numpy as jnp
     from repro.core.filter2d import filter2d
-    from repro.core.distributed import filter2d_sharded
+    from repro.core.pipeline import Filter2D
     from repro.core.borders import BorderSpec
     from repro.core.requant import RequantSpec
     mesh = jax.make_mesh((4,), ("data",))
@@ -67,12 +69,15 @@ def test_sharded_fixed_point_narrow_ring_and_requant():
         spec = BorderSpec(pol, 2.0)
         ref = filter2d(jnp.asarray(x), jnp.asarray(k), border=spec,
                        requant=rq)
-        y = filter2d_sharded(jnp.asarray(x), jnp.asarray(k), mesh,
-                             border=spec, requant=rq)
+        cf = Filter2D(window=3, border=spec, dtype="int8",
+                      requant=rq).compile(x.shape, "sharded", mesh=mesh)
+        y = cf(jnp.asarray(x), jnp.asarray(k))
         assert y.dtype == jnp.int8
         np.testing.assert_array_equal(np.asarray(y), np.asarray(ref))
     # wire dtype: the ring must carry storage-width halo rows
-    fn = jax.jit(lambda a, b: filter2d_sharded(a, b, mesh))
+    cf = Filter2D(window=5, dtype="int8").compile((1, 64, 128, 1),
+                                                  "sharded", mesh=mesh)
+    fn = jax.jit(lambda a, b: cf(a, b))
     txt = fn.lower(jax.ShapeDtypeStruct((1, 64, 128, 1), jnp.int8),
                    jax.ShapeDtypeStruct((5, 5), jnp.int32)
                    ).compile().as_text()
@@ -153,13 +158,14 @@ def test_collective_parser_sees_halo_permutes():
     """Roofline HLO parser finds the ppermute bytes of the halo exchange."""
     _run("""
     import numpy as np, jax, jax.numpy as jnp
-    from repro.core.distributed import filter2d_sharded
+    from repro.core.pipeline import Filter2D
     from repro.core import filters
     from repro.launch.roofline import parse_collective_bytes
     mesh = jax.make_mesh((4,), ("data",))
     x = jax.ShapeDtypeStruct((1, 64, 128, 1), jnp.float32)
     k = jax.ShapeDtypeStruct((5, 5), jnp.float32)
-    fn = jax.jit(lambda a, b: filter2d_sharded(a, b, mesh))
+    cf = Filter2D(window=5).compile(x.shape, "sharded", mesh=mesh)
+    fn = jax.jit(lambda a, b: cf(a, b))
     txt = fn.lower(x, k).compile().as_text()
     coll = parse_collective_bytes(txt)
     assert coll.get("collective-permute", 0) > 0, coll

@@ -32,8 +32,6 @@ CORE_ALL = [
     "decompose_separable",
     "default_bank",
     "filter2d",
-    "filter2d_sharded",
-    "filter2d_streaming",
     "filter2d_xla",
     "filter_bank",
     "macs_per_pixel",

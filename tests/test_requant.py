@@ -16,7 +16,7 @@ from repro.core.border_spec import BorderSpec, SAME_SIZE_POLICIES
 from repro.core.filter2d import apply_requant, filter2d, filter_bank
 from repro.core.requant import (ROUNDING_MODES, RequantSpec, requantize_ref,
                                 round_shift_ref)
-from repro.core.streaming import filter2d_streaming
+from repro.core.pipeline import Filter2D
 from repro.kernels.filter2d import (filter2d_pallas, filter_bank_pallas,
                                     hbm_bytes_per_pixel,
                                     hbm_write_bytes_per_pixel, make_plan,
@@ -182,8 +182,10 @@ def test_streaming_executor_requant_parity(policy, rng):
     x = _frame(rng, np.int8, (32, 40))
     k = rng.integers(-4, 5, (3, 3)).astype(np.int32)
     rq = RequantSpec(multiplier=7, shift=9, rounding="truncate", dtype="int8")
-    got = filter2d_streaming(jnp.asarray(x), jnp.asarray(k), strip_h=8,
-                             border=BorderSpec(policy, 2.0), requant=rq)
+    spec = Filter2D(window=3, border=BorderSpec(policy, 2.0), dtype="int8",
+                    requant=rq.gain_free())
+    got = spec.compile(x.shape, "streaming", strip_h=8)(
+        jnp.asarray(x), jnp.asarray(k), gains=rq)
     assert got.dtype == jnp.int8
     np.testing.assert_array_equal(np.asarray(got),
                                   _ref(x, k, policy, rq, c=2.0))
